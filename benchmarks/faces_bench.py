@@ -2,7 +2,7 @@
 
 Reproduces each figure's *experimental contrast* on the CPU-device grid
 (absolute Slingshot timings need the NIC; the control-path contrasts do
-not — see DESIGN.md §9):
+not):
 
 fig8   64×1×1-style 1-D multi-rank: baseline (host-orchestrated, batch
        sync) vs ST-emulated (host engine, per-op sync — the progress-

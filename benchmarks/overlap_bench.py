@@ -98,7 +98,6 @@ def _run_decomposed():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import jit_shard_map
     from repro.core import overlap
     from repro.launch.hlo_analysis import analyze_collectives
     from repro.parallel import make_mesh
@@ -108,8 +107,8 @@ def _run_decomposed():
     print("Decomposed/overlapped collectives (8-device ring)")
 
     def smap(f, in_specs, out_specs):
-        return jit_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
+        return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
 
     rng = np.random.RandomState(0)
     x = rng.randn(1024, 512).astype(np.float32)   # gathered over rows

@@ -185,6 +185,9 @@ def main() -> None:
     from benchmarks import api_overhead, faces_bench, overlap_bench, \
         serve_bench
     from benchmarks import roofline as roofline_mod
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     argv = sys.argv[1:]
     check_path = None

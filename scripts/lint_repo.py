@@ -1,18 +1,7 @@
 #!/usr/bin/env python
-"""Repo lint: jax version shims must come from ``repro/compat.py``.
+"""Repo lint: declared builder effects.
 
-ROADMAP rule: jax-version compatibility shims (shard_map / AxisType /
-pallas CompilerParams / axis_size) live in ``src/repro/compat.py``; new
-code imports the shim instead of feature-testing jax at call sites.
-This AST lint enforces it:
-
-* no ``getattr``/``hasattr`` feature-tests against the shimmed names
-  outside compat.py — ``getattr(jax, "shard_map", None)`` scattered
-  through call sites is exactly the drift the rule forbids;
-* no direct ``jax.experimental.shard_map`` imports outside compat.py —
-  the legacy spelling is compat.py's fallback, not an API.
-
-A second rule guards the STProve effect substrate: shipped program
+The rule guards the STProve effect substrate: shipped program
 *builders* (:data:`EFFECT_DECLARING`) must pass explicit ``reads=`` and
 ``writes=`` to every ``enqueue_compute`` call.  The no-argument form is
 a legal convenience for exploratory user code — the queue substitutes a
@@ -21,8 +10,7 @@ shipped builders implicit effects over-serialize the happens-before
 graph and weaken the race rules (ST015–ST018), so the AST lint bans it
 at the source.
 
-Scans ``src/``, ``tests/``, ``benchmarks/``, and ``scripts/``.  Prints
-``file:line: message`` per violation and exits non-zero if any are
+Prints ``file:line: message`` per violation and exits non-zero if any are
 found (the CI lint job runs this next to ``python -m repro.analysis``).
 """
 
@@ -33,13 +21,6 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SCAN_DIRS = ("src", "tests", "benchmarks", "scripts")
-EXEMPT = os.path.join("src", "repro", "compat.py")
-
-#: names whose presence-probing belongs in compat.py only
-SHIMMED = {"shard_map", "axis_size", "AxisType", "CompilerParams",
-           "TPUCompilerParams", "check_vma", "check_rep"}
-LEGACY_MODULE = "jax.experimental.shard_map"
 
 #: shipped builders where every enqueue_compute must declare its effect
 #: set explicitly (reads= AND writes=) — see module docstring
@@ -48,21 +29,6 @@ EFFECT_DECLARING = {
     os.path.join("src", "repro", "core", "halo.py"),
     os.path.join("src", "repro", "launch", "serve.py"),
 }
-
-
-def _feature_test_name(node: ast.Call):
-    """The probed attribute name, if this call is getattr/hasattr with a
-    literal name in the shimmed set."""
-    fn = node.func
-    if not (isinstance(fn, ast.Name) and fn.id in ("getattr", "hasattr")):
-        return None
-    if len(node.args) < 2:
-        return None
-    probe = node.args[1]
-    if isinstance(probe, ast.Constant) and isinstance(probe.value, str) \
-            and probe.value in SHIMMED:
-        return probe.value
-    return None
 
 
 def _implicit_enqueue_compute(node: ast.Call) -> bool:
@@ -85,50 +51,20 @@ def lint_file(path: str, rel: str):
     except SyntaxError as e:  # pragma: no cover - repo must parse
         return [(rel, e.lineno or 0, f"syntax error: {e.msg}")]
 
-    declare_effects = rel in EFFECT_DECLARING
     out = []
     for node in ast.walk(tree):
-        if isinstance(node, ast.Call):
-            name = _feature_test_name(node)
-            if name is not None:
-                out.append((rel, node.lineno,
-                            f"feature-test of shimmed name {name!r} — "
-                            f"import the shim from repro/compat.py instead"))
-            if declare_effects and _implicit_enqueue_compute(node):
-                out.append((rel, node.lineno,
-                            "enqueue_compute without explicit reads=/"
-                            "writes= — shipped builders must declare "
-                            "effect sets (implicit fallback is ST019)"))
-        elif isinstance(node, ast.ImportFrom):
-            if node.module and node.module.startswith(LEGACY_MODULE):
-                out.append((rel, node.lineno,
-                            f"direct import of {LEGACY_MODULE} — use "
-                            f"repro.compat.shard_map"))
-        elif isinstance(node, ast.Import):
-            for alias in node.names:
-                if alias.name.startswith(LEGACY_MODULE):
-                    out.append((rel, node.lineno,
-                                f"direct import of {LEGACY_MODULE} — use "
-                                f"repro.compat.shard_map"))
+        if isinstance(node, ast.Call) and _implicit_enqueue_compute(node):
+            out.append((rel, node.lineno,
+                        "enqueue_compute without explicit reads=/"
+                        "writes= — shipped builders must declare "
+                        "effect sets (implicit fallback is ST019)"))
     return out
 
 
 def main(argv=None) -> int:
     violations = []
-    for d in SCAN_DIRS:
-        root = os.path.join(REPO, d)
-        if not os.path.isdir(root):
-            continue
-        for dirpath, _, files in os.walk(root):
-            for fn in sorted(files):
-                if not fn.endswith(".py"):
-                    continue
-                path = os.path.join(dirpath, fn)
-                rel = os.path.relpath(path, REPO)
-                if rel == EXEMPT or rel == os.path.join("scripts",
-                                                        "lint_repo.py"):
-                    continue
-                violations.extend(lint_file(path, rel))
+    for rel in sorted(EFFECT_DECLARING):
+        violations.extend(lint_file(os.path.join(REPO, rel), rel))
 
     for rel, line, msg in violations:
         print(f"{rel}:{line}: {msg}")

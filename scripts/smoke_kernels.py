@@ -12,16 +12,6 @@ np.testing.assert_allclose(ops.halo_pack(u, region), ref.halo_pack(jnp.asarray(u
 msg = rng.randn(1, 5, 4).astype(np.float32)
 np.testing.assert_allclose(ops.halo_unpack_add(u, msg, region),
                            ref.halo_unpack_add(jnp.asarray(u), jnp.asarray(msg), region))
-regions = [
-    (slice(0, 1), slice(0, 5), slice(0, 4)),
-    (slice(5, 6), slice(0, 5), slice(0, 4)),
-    (slice(0, 6), slice(0, 1), slice(0, 4)),
-    (slice(0, 1), slice(0, 1), slice(0, 1)),
-]
-np.testing.assert_allclose(ops.pack_boundary(u, regions), ref.pack_boundary(jnp.asarray(u), regions))
-buf = rng.randn(sum(np.prod([s.stop - s.start for s in r]) for r in regions)).astype(np.float32)
-np.testing.assert_allclose(ops.unpack_boundary_add(u, buf, regions),
-                           ref.unpack_boundary_add(jnp.asarray(u), jnp.asarray(buf), regions), rtol=1e-6)
 print("halo kernels OK")
 
 # rmsnorm

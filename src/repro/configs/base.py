@@ -93,7 +93,6 @@ class ModelConfig:
     param_dtype: str = "float32"
     remat: str = "block"         # none | block (checkpoint each layer block)
     scan_layers: bool = True
-    use_flash_kernel: bool = False  # Pallas attention in prefill path
     use_ssd_kernel: bool = False    # Pallas SSD in ssm fwd path
 
     # long-context serving: archs that can run long_500k

@@ -24,5 +24,5 @@ CONFIG = ModelConfig(
     frontend_dim=1280,      # stub supplies post-conv d_model embeddings
     norm_eps=1e-5,
     tie_embeddings=True,
-    long_context_ok=False,  # 448-token decoder spec; long_500k skipped (DESIGN §5)
+    long_context_ok=False,  # 448-token decoder spec; long_500k skipped (launch/dryrun.py SKIPS)
 )

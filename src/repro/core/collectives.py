@@ -314,9 +314,8 @@ class CollectiveMatmul:
 
 
 def _smap_ref(mesh, fn, in_specs, out_specs):
-    from repro.compat import jit_shard_map
-    return jit_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def build_all_gather_matmul(mesh, axis: str, m: int, k: int, n_out: int,

@@ -266,7 +266,7 @@ class RecvDesc:
 
 @dataclasses.dataclass
 class CollDesc:
-    """A deferred collective (beyond-paper extension, §DESIGN 4)."""
+    """A deferred collective (beyond-paper extension)."""
 
     op: str  # all_gather | reduce_scatter | all_reduce | all_to_all | ppermute
     buf: str

@@ -44,8 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from . import counters
 from .descriptors import (
     CollDesc,
@@ -66,15 +64,12 @@ def _axes_tuple(axis) -> Tuple[str, ...]:
 
 
 def _ensure_vma(x, axis_names: Tuple[str, ...]):
-    """Make `x` explicitly varying over `axis_names` (new-style shard_map
-    tracks a "varying manual axes" set; constants need `pvary`)."""
-    try:
-        cur = jax.typeof(x).vma  # type: ignore[attr-defined]
-    except Exception:
-        return x
+    """Make `x` explicitly varying over `axis_names` (shard_map tracks a
+    "varying manual axes" set; constants must be cast to varying)."""
+    cur = jax.typeof(x).vma
     missing = tuple(a for a in axis_names if a not in cur)
     if missing:
-        x = jax.lax.pvary(x, missing)
+        x = jax.lax.pcast(x, missing, to="varying")
     return x
 
 
@@ -147,18 +142,29 @@ class FusedEngine:
         }
 
     def init_buffers(self, init: Optional[Dict[str, Any]] = None) -> Dict[str, jax.Array]:
-        """Device-place (and shard) the program's buffers."""
+        """Place the program's buffers, each shard straight on its device.
+
+        A host value is split on the host and each shard copied to its
+        own device; a device array is resharded; the other buffers are
+        zeros made in place by one program with the buffers' shardings.
+        No global array is staged whole on one device first.
+        """
         init = init or {}
+        shardings = self.shardings()
+        buffers = self.program.buffers
         out = {}
-        for name, spec in self.program.buffers.items():
-            sh = NamedSharding(self.mesh, P(*spec.pspec))
-            if name in init:
-                out[name] = jax.device_put(jnp.asarray(init[name], spec.dtype), sh)
-            else:
-                out[name] = jax.device_put(
-                    jnp.zeros(spec.shape, spec.dtype), sh
-                )
-        return out
+        for name, value in init.items():
+            dtype = buffers[name].dtype
+            value = (value.astype(dtype) if isinstance(value, jax.Array)
+                     else np.asarray(value, dtype))
+            out[name] = jax.device_put(value, shardings[name])
+        zeros = {n: s for n, s in buffers.items() if n not in init}
+        if zeros:
+            out.update(jax.jit(
+                lambda: {n: jnp.zeros(s.shape, s.dtype)
+                         for n, s in zeros.items()},
+                out_shardings={n: shardings[n] for n in zeros})())
+        return {n: out[n] for n in buffers}
 
     def compile(self):
         if self._jitted is None:
@@ -193,7 +199,7 @@ class FusedEngine:
         # check_vma=False: Pallas calls inside the program can't declare
         # varying-mesh-axes on their out_shapes; ordering is enforced by
         # the token ties, not by vma tracking.
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=self.mesh, in_specs=(specs,), out_specs=specs,
             check_vma=False,
         )

@@ -28,17 +28,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
+from jax.sharding import PartitionSpec as P
 
 from .descriptors import CollDesc, KernelDesc, StartDesc, WaitDesc
-from .engine_fused import _axes_tuple, _ensure_vma, _linear_rank
+from .engine_fused import FusedEngine, _axes_tuple, _ensure_vma, _linear_rank
 from .matching import Channel
 from .queue import STProgram
 
@@ -78,22 +76,8 @@ class HostEngine:
 
     # -- buffers (same layout as the fused engine) ----------------------------
 
-    def shardings(self) -> Dict[str, NamedSharding]:
-        return {
-            name: NamedSharding(self.mesh, P(*spec.pspec))
-            for name, spec in self.program.buffers.items()
-        }
-
-    def init_buffers(self, init: Optional[Dict[str, Any]] = None) -> Dict[str, jax.Array]:
-        init = init or {}
-        out = {}
-        for name, spec in self.program.buffers.items():
-            sh = NamedSharding(self.mesh, P(*spec.pspec))
-            if name in init:
-                out[name] = jax.device_put(jnp.asarray(init[name], spec.dtype), sh)
-            else:
-                out[name] = jax.device_put(jnp.zeros(spec.shape, spec.dtype), sh)
-        return out
+    shardings = FusedEngine.shardings
+    init_buffers = FusedEngine.init_buffers
 
     # -- execution ------------------------------------------------------------
 
@@ -164,8 +148,8 @@ class HostEngine:
                 return tuple(fixed)
 
             self._kernel_cache[key] = jax.jit(
-                shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+                jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                              out_specs=out_specs, check_vma=False)
             )
         return self._kernel_cache[key]
 
@@ -198,9 +182,9 @@ class HostEngine:
                 return dst, received
 
             self._channel_cache[key] = jax.jit(
-                shard_map(body, mesh=self.mesh,
-                          in_specs=(src_spec, dst_spec),
-                          out_specs=(dst_spec, src_spec), check_vma=False)
+                jax.shard_map(body, mesh=self.mesh,
+                              in_specs=(src_spec, dst_spec),
+                              out_specs=(dst_spec, src_spec), check_vma=False)
             )
         return self._channel_cache[key]
 
@@ -235,7 +219,7 @@ class HostEngine:
                 return _ensure_vma(out.astype(prog.buffers[coll.out].dtype), out_axes)
 
             self._coll_cache[key] = jax.jit(
-                shard_map(body, mesh=self.mesh, in_specs=(in_spec,),
-                          out_specs=out_spec, check_vma=False)
+                jax.shard_map(body, mesh=self.mesh, in_specs=(in_spec,),
+                              out_specs=out_spec, check_vma=False)
             )
         return self._coll_cache[key]

@@ -126,8 +126,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .descriptors import KernelDesc, StartDesc
 from .engine_fused import FusedEngine, _interpret_program, fresh_token_banks
 from .queue import STProgram
@@ -381,7 +379,7 @@ class PersistentEngine(FusedEngine):
                 coalesce=self.coalesce,
                 sanitize=self.sanitize,
             )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=self.mesh, in_specs=(specs,), out_specs=out_specs,
             check_vma=False,
         )

@@ -31,13 +31,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as _compat_axis_size
-
 from . import counters
-
-
-def _axis_size(axis) -> int:
-    return _compat_axis_size(axis)
 
 
 def _shift_perm(n: int, delta: int):
@@ -56,7 +50,7 @@ def all_gather_ring(x: jax.Array, axis: str, *, bidirectional: bool = True,
     Bidirectional halves the number of serial steps (ceil((n-1)/2)) by
     sending both ways — the ICI-friendly schedule on a torus.
     """
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -90,7 +84,7 @@ def all_gather_ring(x: jax.Array, axis: str, *, bidirectional: bool = True,
 
 def reduce_scatter_ring(x: jax.Array, axis: str, *, tile_axis: int = 0) -> jax.Array:
     """Ring reduce-scatter of `x` (full-size input, 1/n-size output)."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -130,7 +124,7 @@ def all_gather_matmul(x: jax.Array, w: jax.Array, axis: str,
 
     Returns [m_local * n_axis, n].
     """
-    n_dev = _axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     if transpose_w:
         w = w.T
     if n_dev == 1:
@@ -161,7 +155,7 @@ def matmul_reduce_scatter(x: jax.Array, w: jax.Array, axis: str) -> jax.Array:
 
     Returns [m // n_axis, n].
     """
-    n_dev = _axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     y_local = x @ w  # [m, n] partial sum
     if n_dev == 1:
         return y_local
@@ -191,7 +185,7 @@ def all_to_all_ppermute(x: jax.Array, axis: str, *, split_axis: int = 0) -> jax.
     expressed as n-1 permutes the ST way (each round is one deferred
     descriptor batch).
     """
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis)

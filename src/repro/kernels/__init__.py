@@ -1,21 +1,20 @@
 """Pallas TPU kernels for the perf-critical compute hot-spots.
 
 Each kernel: <name>.py (pl.pallas_call + explicit BlockSpec VMEM tiling),
-a jit'd wrapper in ops.py, and a pure-jnp oracle in ref.py.  Validated on
-CPU with interpret=True; TPU is the compile target.
+a jit'd wrapper in ops.py, and a pure-jnp oracle in ref.py.  The
+wrappers compile the kernel for the TPU and interpret it where the
+program is lowered for the CPU.
 """
 from . import ops, ref
 from .ops import (
     flash_attention,
     halo_pack,
     halo_unpack_add,
-    pack_boundary,
     rmsnorm,
     ssd_scan,
-    unpack_boundary_add,
 )
 
 __all__ = [
     "ops", "ref", "flash_attention", "halo_pack", "halo_unpack_add",
-    "pack_boundary", "rmsnorm", "ssd_scan", "unpack_boundary_add",
+    "rmsnorm", "ssd_scan",
 ]

@@ -22,7 +22,6 @@ import functools
 from typing import Optional
 
 import jax
-from repro.compat import tpu_compiler_params
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -149,7 +148,7 @@ def flash_attention_call(
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
             pltpu.VMEM((block_q, 128), jnp.float32),  # running denom
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -6,39 +6,37 @@ elements" before sending, and kernels that add received messages back
 after the wait.  These are the compute hot-spots of the communication
 loop, so they get Pallas kernels:
 
-* ``halo_pack_kernel``          — extract one static boundary slab;
-* ``halo_unpack_add_kernel``    — add one received slab into the block;
-* ``pack_boundary_kernel``      — all 26 regions into ONE contiguous 1-D
-                                  buffer (the paper's "contiguous MPI
-                                  buffer"), static region offsets;
-* ``unpack_boundary_add_kernel``— scatter-add the contiguous buffer back;
-* ``pack_segments_kernel``      — N *separate* source slabs into ONE
-                                  contiguous staging buffer at static
-                                  offsets: the same layout a
-                                  :class:`~repro.core.matching.CoalescedChannel`
-                                  fused transfer stages (members send
-                                  from distinct buffers).  The engines
-                                  currently lower that pack with
-                                  ``jnp.concatenate`` (which XLA:CPU
-                                  fuses best); this Pallas kernel is
-                                  the parity-tested TPU drop-in
-                                  (ROADMAP follow-on), not yet wired
-                                  into ``_run_coalesced_batch``;
-* ``unpack_segments_kernel``    — split the received staging buffer back
-                                  into the per-member slabs (inverse;
-                                  same status).
+* ``halo_pack_call``       — extract one static boundary slab;
+* ``halo_unpack_add_call`` — add one received slab into the block, in
+                             place (the block is aliased to the output);
+* ``pack_segments_call``   — N *separate* source slabs into ONE
+                             contiguous staging buffer at static
+                             offsets: the same layout a
+                             :class:`~repro.core.matching.CoalescedChannel`
+                             fused transfer stages (members send from
+                             distinct buffers).  The engines currently
+                             lower that pack with ``jnp.concatenate``;
+                             this kernel is the parity-tested drop-in,
+                             not yet wired into ``_run_coalesced_batch``;
+* ``unpack_segments_call`` — split the received staging buffer back into
+                             the per-member slabs (inverse; same status).
 
-TPU adaptation: a face slab of a local (px,py,pz) block is at most
-px·py ≲ 10⁴ elements — far below VMEM, so each kernel runs as a single
-grid cell with whole-block BlockSpecs in VMEM, and the packing loop is
-fully unrolled over static regions (the MXU is not involved; this is a
-VPU copy/accumulate kernel).  For blocks too large for VMEM the wrapper
-falls back to tiling along the leading axis.
+TPU adaptation: a Faces block is far larger than VMEM (256³ float32 is
+64 MiB), so the slab kernels never load the block whole.  Each one
+pipelines, over chunks of the slab's leading axis, only the *window* of
+the block that holds the slab: along the two minor axes the window is
+the smallest (sublane, lane)-tile-aligned block containing the slab's
+extent (or the whole axis where the axis is not tile-divisible), so a
+z-face reads a 128-lane column band and an x-face one plane.  The
+unpack-add kernel aliases the block to its output and writes back only
+the visited windows; the rest of the block is never touched.  Both are
+VPU copy/accumulate kernels (the MXU is not involved).
 """
 
 from __future__ import annotations
 
-import functools
+import dataclasses
+import math
 from typing import Sequence, Tuple
 
 import jax
@@ -46,13 +44,85 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+#: VMEM bytes one pipelined block may take.  Pallas double-buffers every
+#: operand, so the unpack-add kernel's three blocks stay under 12 MiB of
+#: the 16 MiB scoped-VMEM default of a v5e.
+_BLOCK_BYTES = 2 << 20
+
+_LANES = 128
+
 
 def _region_shape(region: Tuple[slice, ...]) -> Tuple[int, ...]:
     return tuple(s.stop - s.start for s in region)
 
 
-def _region_size(region: Tuple[slice, ...]) -> int:
-    return int(np.prod(_region_shape(region)))
+def _sublanes(dtype) -> int:
+    """Second-minor tile height of a VMEM array of ``dtype`` (32-bit
+    words hold 1, 2 or 4 elements)."""
+    return 8 * max(1, 4 // np.dtype(dtype).itemsize)
+
+
+def _tiled_bytes(shape, dtype) -> int:
+    """VMEM bytes of a block of ``shape`` once its two minor axes are
+    padded to whole tiles."""
+    *lead, rows, cols = shape
+    sub = _sublanes(dtype)
+    padded = (-(-rows // sub) * sub) * (-(-cols // _LANES) * _LANES)
+    return int(np.prod(lead, dtype=np.int64)) * padded * np.dtype(dtype).itemsize
+
+
+def _axis_window(start: int, stop: int, n: int, tile: int) -> Tuple[int, int]:
+    """``(block, index)`` of the smallest tile-aligned block along an
+    axis of length ``n`` that holds ``[start, stop)``."""
+    if n % tile:
+        return n, 0  # a block equal to the whole axis is always legal
+    for w in range(tile, n + 1, tile):
+        if n % w == 0 and start // w == (stop - 1) // w:
+            return w, start // w
+    return n, 0
+
+
+@dataclasses.dataclass(frozen=True)
+class _SlabWindow:
+    """How a slab kernel walks ``region`` of a 3-D block: ``grid`` steps
+    of ``chunk`` leading-axis planes, each reading the block window
+    ``block`` at ``index(i)``; ``rel`` locates the slab in the window."""
+
+    chunk: int
+    grid: int
+    block: Tuple[int, int, int]
+    minor_index: Tuple[int, int]
+    lead_offset: int
+    rel: Tuple[slice, ...]
+
+    def index(self, i):
+        return (self.lead_offset + i, *self.minor_index)
+
+
+def _slab_window(shape, region, dtype) -> _SlabWindow:
+    if len(shape) != 3 or len(region) != 3:
+        raise ValueError(
+            f"slab kernels take a 3-D block and region, got shape {shape}")
+    for s, n in zip(region, shape):
+        if not 0 <= s.start < s.stop <= n:
+            raise ValueError(f"region {region} is outside block {shape}")
+    (a0, b0), (a1, b1), (a2, b2) = ((s.start, s.stop) for s in region)
+    w1, k1 = _axis_window(a1, b1, shape[1], _sublanes(dtype))
+    w2, k2 = _axis_window(a2, b2, shape[2], _LANES)
+    # chunks of c planes must tile [a0, b0) on block boundaries: c | a0
+    # and c | (b0 - a0); take the largest whose windows fit the budget
+    lead = math.gcd(a0, b0 - a0)
+    slab = _region_shape(region)
+    chunk = max(
+        (c for c in range(1, lead + 1) if lead % c == 0
+         and max(_tiled_bytes((c, w1, w2), dtype),
+                 _tiled_bytes((c,) + slab[1:], dtype)) <= _BLOCK_BYTES),
+        default=1)
+    rel = (slice(0, chunk), slice(a1 - k1 * w1, b1 - k1 * w1),
+           slice(a2 - k2 * w2, b2 - k2 * w2))
+    return _SlabWindow(chunk=chunk, grid=(b0 - a0) // chunk,
+                       block=(chunk, w1, w2), minor_index=(k1, k2),
+                       lead_offset=a0 // chunk, rel=rel)
 
 
 # --------------------------------------------------------------------------
@@ -60,90 +130,51 @@ def _region_size(region: Tuple[slice, ...]) -> int:
 # --------------------------------------------------------------------------
 
 
-def _pack_body(u_ref, out_ref, *, region):
-    out_ref[...] = u_ref[region]
-
-
 def halo_pack_call(u: jax.Array, region: Tuple[slice, ...], *,
                    interpret: bool = False) -> jax.Array:
-    shape = _region_shape(region)
+    """``u[region]`` for a static region of a 3-D block."""
+    win = _slab_window(u.shape, region, u.dtype)
+    slab = _region_shape(region)
+
+    def body(u_ref, out_ref):
+        out_ref[...] = u_ref[win.rel]
+
     return pl.pallas_call(
-        functools.partial(_pack_body, region=region),
-        out_shape=jax.ShapeDtypeStruct(shape, u.dtype),
-        in_specs=[pl.BlockSpec(u.shape, lambda: (0,) * u.ndim)],
-        out_specs=pl.BlockSpec(shape, lambda: (0,) * len(shape)),
+        body,
+        out_shape=jax.ShapeDtypeStruct(slab, u.dtype),
+        grid=(win.grid,),
+        in_specs=[pl.BlockSpec(win.block, win.index)],
+        out_specs=pl.BlockSpec((win.chunk,) + slab[1:], lambda i: (i, 0, 0)),
         interpret=interpret,
     )(u)
-
-
-def _unpack_add_body(u_ref, msg_ref, out_ref, *, region):
-    out_ref[...] = u_ref[...]
-    out_ref[region] = u_ref[region] + msg_ref[...].astype(u_ref.dtype)
 
 
 def halo_unpack_add_call(u: jax.Array, msg: jax.Array,
                          region: Tuple[slice, ...], *,
                          interpret: bool = False) -> jax.Array:
+    """``u.at[region].add(msg)``, updating ``u``'s buffer in place: only
+    the windows holding the slab are read and written back."""
+    win = _slab_window(u.shape, region, u.dtype)
+    slab = _region_shape(region)
+    if tuple(msg.shape) != slab:
+        raise ValueError(f"message shape {msg.shape} != region shape {slab}")
+
+    def body(u_ref, msg_ref, out_ref):
+        out_ref[...] = u_ref[...]
+        out_ref[win.rel] = u_ref[win.rel] + msg_ref[...].astype(u_ref.dtype)
+
     return pl.pallas_call(
-        functools.partial(_unpack_add_body, region=region),
+        body,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
+        grid=(win.grid,),
         in_specs=[
-            pl.BlockSpec(u.shape, lambda: (0,) * u.ndim),
-            pl.BlockSpec(msg.shape, lambda: (0,) * msg.ndim),
+            pl.BlockSpec(win.block, win.index),
+            pl.BlockSpec((win.chunk,) + slab[1:], lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec(u.shape, lambda: (0,) * u.ndim),
+        out_specs=pl.BlockSpec(win.block, win.index),
+        input_output_aliases={0: 0},
         interpret=interpret,
     )(u, msg)
-
-
-# --------------------------------------------------------------------------
-# contiguous 26-region pack / unpack (paper-faithful "one MPI buffer")
-# --------------------------------------------------------------------------
-
-
-def _pack_boundary_body(u_ref, out_ref, *, regions):
-    off = 0
-    for r in regions:  # static unroll
-        size = _region_size(r)
-        out_ref[pl.ds(off, size)] = u_ref[r].reshape(-1)
-        off += size
-
-
-def pack_boundary_call(u: jax.Array, regions: Sequence[Tuple[slice, ...]], *,
-                       interpret: bool = False) -> jax.Array:
-    total = sum(_region_size(r) for r in regions)
-    return pl.pallas_call(
-        functools.partial(_pack_boundary_body, regions=tuple(regions)),
-        out_shape=jax.ShapeDtypeStruct((total,), u.dtype),
-        in_specs=[pl.BlockSpec(u.shape, lambda: (0,) * u.ndim)],
-        out_specs=pl.BlockSpec((total,), lambda: (0,)),
-        interpret=interpret,
-    )(u)
-
-
-def _unpack_boundary_body(u_ref, buf_ref, out_ref, *, regions):
-    out_ref[...] = u_ref[...]
-    off = 0
-    for r in regions:  # static unroll; overlapping regions accumulate
-        size = _region_size(r)
-        seg = buf_ref[pl.ds(off, size)].reshape(_region_shape(r))
-        out_ref[r] = out_ref[r] + seg.astype(out_ref.dtype)
-        off += size
-
-
-def unpack_boundary_add_call(u: jax.Array, buf: jax.Array,
-                             regions: Sequence[Tuple[slice, ...]], *,
-                             interpret: bool = False) -> jax.Array:
-    return pl.pallas_call(
-        functools.partial(_unpack_boundary_body, regions=tuple(regions)),
-        out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
-        in_specs=[
-            pl.BlockSpec(u.shape, lambda: (0,) * u.ndim),
-            pl.BlockSpec(buf.shape, lambda: (0,)),
-        ],
-        out_specs=pl.BlockSpec(u.shape, lambda: (0,) * u.ndim),
-        interpret=interpret,
-    )(u, buf)
 
 
 # --------------------------------------------------------------------------
@@ -155,8 +186,8 @@ def _pack_segments_body(*refs):
     *in_refs, out_ref = refs
     off = 0
     for r in in_refs:  # static unroll over the group's members
-        size = int(np.prod(r.shape))
-        out_ref[pl.ds(off, size)] = r[...].reshape(-1)
+        size = r.shape[0]
+        out_ref[pl.ds(off, size)] = r[...]
         off += size
 
 
@@ -164,11 +195,13 @@ def pack_segments_call(arrays: Sequence[jax.Array], *,
                        interpret: bool = False) -> jax.Array:
     """Pack N source slabs into ONE contiguous 1-D staging buffer.
 
-    The coalescing analogue of :func:`pack_boundary_call`: member slabs
-    live in *separate* buffers (one per matched channel), and each lands
+    The paper's contiguous MPI buffer: member slabs live in *separate*
+    buffers (one per matched channel), and each lands
     at a static offset — the layout recorded in the batch's
     :class:`~repro.core.matching.CoalescePlan`.  All slabs must share a
-    dtype (the plan groups by dtype).
+    dtype (the plan groups by dtype).  Slabs are flattened before the
+    kernel (Mosaic lowers no in-kernel reshape of a tiled slab to 1-D),
+    so the kernel copies 1-D segments only.
 
     Status: the engines stage this layout with ``jnp.concatenate``
     (see ``engine_fused._run_coalesced_batch``); this kernel is the
@@ -180,41 +213,42 @@ def pack_segments_call(arrays: Sequence[jax.Array], *,
     dtype = arrays[0].dtype
     if any(a.dtype != dtype for a in arrays):
         raise ValueError("coalesced segments must share a dtype")
-    total = sum(int(np.prod(a.shape)) for a in arrays)
+    flat = [jnp.reshape(a, (-1,)) for a in arrays]
+    total = sum(a.shape[0] for a in flat)
     return pl.pallas_call(
         _pack_segments_body,
         out_shape=jax.ShapeDtypeStruct((total,), dtype),
-        in_specs=[pl.BlockSpec(a.shape, lambda _n=a.ndim: (0,) * _n)
-                  for a in arrays],
+        in_specs=[pl.BlockSpec(a.shape, lambda: (0,)) for a in flat],
         out_specs=pl.BlockSpec((total,), lambda: (0,)),
         interpret=interpret,
-    )(*arrays)
+    )(*flat)
 
 
 def _unpack_segments_body(buf_ref, *out_refs):
     off = 0
     for r in out_refs:  # static unroll
-        size = int(np.prod(r.shape))
-        r[...] = buf_ref[pl.ds(off, size)].reshape(r.shape)
+        size = r.shape[0]
+        r[...] = buf_ref[pl.ds(off, size)]
         off += size
 
 
 def unpack_segments_call(buf: jax.Array, shapes: Sequence[Tuple[int, ...]], *,
                          interpret: bool = False) -> Tuple[jax.Array, ...]:
     """Split a received staging buffer back into per-member slabs
-    (inverse of :func:`pack_segments_call`, static offsets)."""
+    (inverse of :func:`pack_segments_call`, static offsets; the kernel
+    writes 1-D segments, reshaped to the slabs outside it)."""
     shapes = [tuple(s) for s in shapes]
-    total = sum(int(np.prod(s)) for s in shapes)
-    if total != int(np.prod(buf.shape)):
+    sizes = [int(np.prod(s)) for s in shapes]
+    if sum(sizes) != int(np.prod(buf.shape)):
         raise ValueError(
-            f"segment shapes cover {total} elements, buffer has "
+            f"segment shapes cover {sum(sizes)} elements, buffer has "
             f"{int(np.prod(buf.shape))}")
     outs = pl.pallas_call(
         _unpack_segments_body,
-        out_shape=tuple(jax.ShapeDtypeStruct(s, buf.dtype) for s in shapes),
+        out_shape=tuple(jax.ShapeDtypeStruct((n,), buf.dtype) for n in sizes),
         in_specs=[pl.BlockSpec(buf.shape, lambda: (0,))],
-        out_specs=tuple(pl.BlockSpec(s, lambda _n=len(s): (0,) * _n)
-                        for s in shapes),
+        out_specs=tuple(pl.BlockSpec((n,), lambda: (0,)) for n in sizes),
         interpret=interpret,
     )(buf)
-    return outs if isinstance(outs, tuple) else (outs,)
+    outs = outs if isinstance(outs, (tuple, list)) else (outs,)
+    return tuple(o.reshape(s) for o, s in zip(outs, shapes))

@@ -1,37 +1,36 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Every op auto-selects ``interpret=True`` on non-TPU backends (this
-container is CPU-only; the kernels are *written for* TPU and *validated*
-in interpret mode against :mod:`.ref`).  Set ``REPRO_PALLAS_INTERPRET=0``
-to force compiled mode (on TPU), ``=1`` to force interpretation.
+Every op lowers per target platform: where the program is compiled for
+the CPU the kernel runs in the Pallas interpreter (how it is validated
+against :mod:`.ref`), everywhere else it is the compiled Mosaic kernel.
+The choice follows the devices the call is compiled for
+(``lax.platform_dependent`` lowers only the branch of that platform),
+not the process's default backend, so there is no switch that runs a
+kernel interpreted on the chip.
 """
 
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention_call
-from .halo_pack import (
-    halo_pack_call,
-    halo_unpack_add_call,
-    pack_boundary_call,
-    unpack_boundary_add_call,
-)
+from .halo_pack import halo_pack_call, halo_unpack_add_call
 from .rmsnorm import rmsnorm_call
 from .ssd_scan import ssd_scan_call
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+def _on_target(kernel, *args, **static):
+    """``kernel(*args, **static, interpret=...)`` with ``interpret`` set
+    by the platform the enclosing program is lowered for."""
+    return jax.lax.platform_dependent(
+        *args,
+        cpu=functools.partial(kernel, **static, interpret=True),
+        default=functools.partial(kernel, **static, interpret=False))
 
 
 def _hashable_region(region):
@@ -45,85 +44,54 @@ def _region_from_hashable(hr):
 # -- halo pack family ---------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _halo_pack(u, hregion, interpret):
-    return halo_pack_call(u, _region_from_hashable(hregion), interpret=interpret)
+@functools.partial(jax.jit, static_argnums=(1,))
+def _halo_pack(u, hregion):
+    return _on_target(halo_pack_call, u, region=_region_from_hashable(hregion))
 
 
-def halo_pack(u: jax.Array, region: Tuple[slice, ...], *,
-              interpret: Optional[bool] = None) -> jax.Array:
-    interpret = _interpret_default() if interpret is None else interpret
-    return _halo_pack(u, _hashable_region(region), interpret)
+def halo_pack(u: jax.Array, region: Tuple[slice, ...]) -> jax.Array:
+    return _halo_pack(u, _hashable_region(region))
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _halo_unpack_add(u, msg, hregion, interpret):
-    return halo_unpack_add_call(u, msg, _region_from_hashable(hregion),
-                                interpret=interpret)
+@functools.partial(jax.jit, static_argnums=(2,))
+def _halo_unpack_add(u, msg, hregion):
+    return _on_target(halo_unpack_add_call, u, msg,
+                      region=_region_from_hashable(hregion))
 
 
-def halo_unpack_add(u: jax.Array, msg: jax.Array, region: Tuple[slice, ...], *,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    interpret = _interpret_default() if interpret is None else interpret
-    return _halo_unpack_add(u, msg, _hashable_region(region), interpret)
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _pack_boundary(u, hregions, interpret):
-    return pack_boundary_call(u, tuple(map(_region_from_hashable, hregions)),
-                              interpret=interpret)
-
-
-def pack_boundary(u: jax.Array, regions: Sequence[Tuple[slice, ...]], *,
-                  interpret: Optional[bool] = None) -> jax.Array:
-    interpret = _interpret_default() if interpret is None else interpret
-    return _pack_boundary(u, tuple(map(_hashable_region, regions)), interpret)
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _unpack_boundary_add(u, buf, hregions, interpret):
-    return unpack_boundary_add_call(
-        u, buf, tuple(map(_region_from_hashable, hregions)), interpret=interpret)
-
-
-def unpack_boundary_add(u: jax.Array, buf: jax.Array,
-                        regions: Sequence[Tuple[slice, ...]], *,
-                        interpret: Optional[bool] = None) -> jax.Array:
-    interpret = _interpret_default() if interpret is None else interpret
-    return _unpack_boundary_add(u, buf, tuple(map(_hashable_region, regions)),
-                                interpret)
+def halo_unpack_add(u: jax.Array, msg: jax.Array,
+                    region: Tuple[slice, ...]) -> jax.Array:
+    return _halo_unpack_add(u, msg, _hashable_region(region))
 
 
 # -- rmsnorm -------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
-def _rmsnorm2d(x, w, eps, weight_offset, block_rows, interpret):
-    return rmsnorm_call(x, w, eps=eps, weight_offset=weight_offset,
-                        block_rows=block_rows, interpret=interpret)
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _rmsnorm2d(x, w, eps, weight_offset, block_rows):
+    return _on_target(rmsnorm_call, x, w, eps=eps, weight_offset=weight_offset,
+                      block_rows=block_rows)
 
 
 def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
-            weight_offset: float = 0.0, block_rows: int = 128,
-            interpret: Optional[bool] = None) -> jax.Array:
+            weight_offset: float = 0.0, block_rows: int = 128) -> jax.Array:
     """Fused RMSNorm over the last dim; any leading dims."""
-    interpret = _interpret_default() if interpret is None else interpret
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    y = _rmsnorm2d(x2, w, eps, weight_offset, block_rows, interpret)
+    y = _rmsnorm2d(x2, w, eps, weight_offset, block_rows)
     return y.reshape(*lead, x.shape[-1])
 
 
 # -- flash attention -----------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(3, 11)))
+@functools.partial(jax.jit, static_argnums=tuple(range(3, 10)))
 def _flash(q, k, v, causal, scale, window, logit_softcap, q_offset,
-           block_q, block_k, interpret):
-    return flash_attention_call(
-        q, k, v, causal=causal, scale=scale, window=window,
-        logit_softcap=logit_softcap, q_offset=q_offset,
-        block_q=block_q, block_k=block_k, interpret=interpret)
+           block_q, block_k):
+    return _on_target(
+        flash_attention_call, q, k, v, causal=causal, scale=scale,
+        window=window, logit_softcap=logit_softcap, q_offset=q_offset,
+        block_q=block_q, block_k=block_k)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -131,33 +99,33 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     logit_softcap: Optional[float] = None,
                     q_offset: int = 0, block_q: int = 128,
-                    block_k: int = 128,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    interpret = _interpret_default() if interpret is None else interpret
+                    block_k: int = 128) -> jax.Array:
     return _flash(q, k, v, causal, scale, window, logit_softcap, q_offset,
-                  block_q, block_k, interpret)
+                  block_q, block_k)
 
 
 # -- SSD scan -------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
-def _ssd(x, dt, A, Bm, C, init_state, chunk, return_state, interpret):
-    return ssd_scan_call(x, dt, A, Bm, C, init_state=init_state, chunk=chunk,
-                         return_state=return_state, interpret=interpret)
+@functools.partial(jax.jit, static_argnums=(6, 7))
+def _ssd(x, dt, A, Bm, C, init_state, chunk, return_state):
+    return _on_target(
+        lambda x, dt, A, Bm, C, s, *, interpret: ssd_scan_call(
+            x, dt, A, Bm, C, init_state=s, chunk=chunk,
+            return_state=return_state, interpret=interpret),
+        x, dt, A, Bm, C, init_state)
 
 
 def ssd_scan(x, dt, A, Bm, C, *, init_state=None, chunk: int = 128,
-             return_state: bool = False, interpret: Optional[bool] = None):
-    interpret = _interpret_default() if interpret is None else interpret
+             return_state: bool = False):
     if init_state is None:
         B, _, H, P = x.shape
         N = Bm.shape[-1]
         init_state = jnp.zeros((B, H, P, N), jnp.float32)
-    return _ssd(x, dt, A, Bm, C, init_state, chunk, return_state, interpret)
+    return _ssd(x, dt, A, Bm, C, init_state, chunk, return_state)
 
 
 __all__ = [
-    "halo_pack", "halo_unpack_add", "pack_boundary", "unpack_boundary_add",
-    "rmsnorm", "flash_attention", "ssd_scan", "ref",
+    "halo_pack", "halo_unpack_add", "rmsnorm", "flash_attention", "ssd_scan",
+    "ref",
 ]

@@ -7,11 +7,10 @@ No Pallas, no tiling — straight-line jnp.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 # --------------------------------------------------------------------------
@@ -27,25 +26,6 @@ def halo_pack(u: jax.Array, region: Tuple[slice, ...]) -> jax.Array:
 def halo_unpack_add(u: jax.Array, msg: jax.Array, region: Tuple[slice, ...]) -> jax.Array:
     """Add a received slab into the block's boundary region."""
     return u.at[region].add(msg.astype(u.dtype))
-
-
-def pack_boundary(u: jax.Array, regions: Sequence[Tuple[slice, ...]]) -> jax.Array:
-    """Paper step-2 semantics: copy faces/edges/corners into ONE
-    contiguous buffer (flattened, region-major, static offsets)."""
-    return jnp.concatenate([u[r].reshape(-1) for r in regions])
-
-
-def unpack_boundary_add(u: jax.Array, buf: jax.Array,
-                        regions: Sequence[Tuple[slice, ...]]) -> jax.Array:
-    """Paper step-6 semantics: add contiguous-buffer segments back into
-    their regions."""
-    off = 0
-    for r in regions:
-        size = int(np.prod([s.stop - s.start for s in r]))
-        seg = buf[off:off + size].reshape([s.stop - s.start for s in r])
-        u = u.at[r].add(seg.astype(u.dtype))
-        off += size
-    return u
 
 
 # --------------------------------------------------------------------------

@@ -25,7 +25,6 @@ import functools
 from typing import Optional, Tuple
 
 import jax
-from repro.compat import tpu_compiler_params
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -126,7 +125,7 @@ def ssd_scan_call(
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -36,7 +36,7 @@ from repro.launch.steps import build_bundle
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                          "artifacts", "dryrun")
 
-# (arch, shape) pairs skipped with a reason (DESIGN.md §5)
+# (arch, shape) pairs skipped, each with its reason
 SKIPS = {
     ("whisper-large-v3", "long_500k"):
         "enc-dec with a 448-token decoder spec; 500k decode is architecture-"

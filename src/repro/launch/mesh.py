@@ -16,19 +16,18 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import auto_axis_types
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over host (CPU) devices for tests/examples."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **auto_axis_types(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # Hardware constants (TPU v5e) used by the roofline analysis.

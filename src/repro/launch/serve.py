@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ModelConfig, ShapeConfig, get_config
 from repro.launch.steps import build_prefill_step, build_serve_step
 from repro.models import Model
@@ -599,6 +600,7 @@ def main():
                     help="decode chunk between admission points")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

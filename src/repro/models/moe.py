@@ -2,7 +2,7 @@
 
 Expert-parallel design: experts shard over the ``model`` mesh axis; the
 dispatch gather/scatter across the expert dimension is exactly the
-paper's sparse-peer communication pattern (§DESIGN 4) — under pjit the
+paper's sparse-peer communication pattern — under pjit the
 partitioner lowers it to all-to-all traffic on the expert axis, and the
 ST benchmarks exercise the same pattern explicitly through
 ``overlap.all_to_all_ppermute``.  :func:`build_moe_dispatch_program`
@@ -30,7 +30,6 @@ import math
 from typing import Dict, Optional, Tuple
 
 import jax
-from repro.compat import shard_map
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -235,8 +234,8 @@ def apply_moe_ep(p, x, cfg: ModelConfig) -> Optional[Tuple[jax.Array, Dict]]:
                 dropped)
 
     out_specs = (x_spec, P(), P(None), P())
-    sm = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     rbias = p.get("router_bias", jnp.zeros((E,), jnp.float32))
     wi_v = _virtualize_in(p["wi"])
     wg_v = _virtualize_in(p["wg"]) if has_wg else wi_v

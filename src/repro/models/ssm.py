@@ -4,7 +4,7 @@ Structure (arXiv:2405.21060): in_proj → [z | x | B | C | dt]; short
 causal depthwise conv on (x,B,C); SSD scan (Pallas kernel or jnp
 oracle); gated RMSNorm; out_proj.
 
-Distribution note (§DESIGN 4): the SSD inner dimension shards over
+Distribution note: the SSD inner dimension shards over
 ``model`` (heads), and for sequence-parallel long-context the
 chunk-boundary state hand-off is a ppermute chain — the ST trigger/wait
 pattern.  Decode carries (conv_state, ssm_state) instead of a KV cache:

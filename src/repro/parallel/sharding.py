@@ -32,16 +32,11 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices=None) -> Mesh:
     """jax.make_mesh with explicit Auto axis types (silences the 0.9
     behaviour-change warning; we use shard_map/pjit auto mode)."""
-    import numpy as np
-
-    from repro.compat import auto_axis_types
-
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
     if devices is None:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes), **auto_axis_types(len(axes)),
-        )
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto)
     dev = np.asarray(devices).reshape(tuple(shape))
-    return Mesh(dev, tuple(axes), **auto_axis_types(len(axes)))
+    return Mesh(dev, tuple(axes), axis_types=auto)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,7 +104,7 @@ def logical_spec_sized(shape: Sequence[int], axes: Sequence[Optional[str]],
 
     Example: a 50280-vocab can't shard 16 ways → the vocab dim falls back
     to replicated; whisper's 20 heads can't shard over model=16 → heads
-    replicated (the arch then runs FSDP+DP only — recorded in DESIGN.md).
+    replicated (the arch then runs FSDP+DP only).
     For tuple assignments like ("pod","data") the prefix subsets are
     tried before giving up.
     """

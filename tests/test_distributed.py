@@ -108,10 +108,9 @@ def test_overlap_collectives_match_lax(subproc):
         mesh = make_mesh((8,), ("x",))
         x = np.random.RandomState(0).randn(32, 16).astype(np.float32)
 
-        from repro.compat import jit_shard_map
         def smap(f, in_spec, out_spec):
-            return jit_shard_map(f, mesh=mesh, in_specs=in_spec,
-                                 out_specs=out_spec)
+            return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_spec,
+                                         out_specs=out_spec, check_vma=False))
 
         # all_gather_ring (both directions) == lax.all_gather
         for bidi in (False, True):

@@ -517,3 +517,37 @@ print("inner steps ok")
 """, devices=1)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "inner steps ok" in r.stdout
+
+
+# -- persistent compile cache of the entry points ------------------------------
+
+
+def test_compile_cache_leaves_an_env_dir_to_jax(monkeypatch):
+    import jax
+    from repro.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax-cache")
+    assert enable_compile_cache() == "/elsewhere/jax-cache"
+    assert jax.config.jax_compilation_cache_dir == before  # nothing set
+
+
+def test_compile_cache_defaults_to_a_fixed_path_in_the_checkout(monkeypatch):
+    import os
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.compile_cache import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path  # same path every run
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()

@@ -16,12 +16,12 @@ import pytest
 
 
 def _smap1(f, in_specs, out_specs):
-    from repro.compat import jit_shard_map
+    import jax
     from repro.parallel import make_mesh
 
     mesh = make_mesh((1,), ("x",))
-    return jit_shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 # -- trivial paths (fast, single device) --------------------------------------
@@ -139,14 +139,13 @@ def test_moe_dispatch_builder_builds_and_runs():
 _PRELUDE = """
 import numpy as np, jax, jax.numpy as jnp
 from functools import partial
-from repro.compat import jit_shard_map
 from repro.core import overlap
 from repro.parallel import make_mesh
 from jax.sharding import PartitionSpec as P
 mesh = make_mesh((8,), ("x",))
 def smap(f, in_specs, out_specs):
-    return jit_shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 """
 
 

@@ -230,6 +230,14 @@ def fresh_token_banks(prog: STProgram):
             {pid: counters.fresh_token() for pid in pids})
 
 
+def _scope_name(d) -> str:
+    """The named scope a descriptor lowers in: a kernel's queue op name,
+    ``wait`` for a wait's gate, ``exchange`` for a batch's transfers."""
+    if isinstance(d, KernelDesc):
+        return d.name
+    return "wait" if isinstance(d, WaitDesc) else "exchange"
+
+
 def _interpret_program(
     mem: Dict[str, jax.Array],
     *,
@@ -307,111 +315,115 @@ def _interpret_program(
 
     for d in prog.descriptors:
         pid = d.pid
-        if isinstance(d, KernelDesc):
-            if tracker is not None:
-                tracker.kernel(d)
-            args = [mem[r] for r in d.reads]
-            if mode == "stream":
-                # strict FIFO: kernel ordered after everything before it
-                # on its OWN program's stream (queues stay independent)
-                tokens[pid], args = counters.tie(tokens[pid], *args)
-            outs = d.fn(*args)
-            if not isinstance(outs, (tuple, list)):
-                outs = (outs,)
-            if len(outs) != len(d.writes):
-                raise ValueError(
-                    f"kernel {d.name!r} returned {len(outs)} values for "
-                    f"{len(d.writes)} write buffers"
-                )
-            for w, o in zip(d.writes, outs):
-                spec = prog.buffers[w].pspec
-                axes = tuple(a for a in jax.tree.leaves(list(spec)) if a)
-                mem[w] = _ensure_vma(o.astype(prog.buffers[w].dtype), axes)
-                if canary_saved:
-                    canary_saved.pop(w, None)  # whole-buffer rewrite
-            if mode == "stream":
-                tokens[pid] = counters.completion_from(
-                    tokens[pid], *[mem[w] for w in d.writes])
+        # the descriptor's ops carry its queue op name in their HLO
+        # op_name metadata, so a device trace can name the stage
+        # that took the time; a scope changes no op
+        with jax.named_scope(_scope_name(d)):
+            if isinstance(d, KernelDesc):
+                if tracker is not None:
+                    tracker.kernel(d)
+                args = [mem[r] for r in d.reads]
+                if mode == "stream":
+                    # strict FIFO: kernel ordered after everything before it
+                    # on its OWN program's stream (queues stay independent)
+                    tokens[pid], args = counters.tie(tokens[pid], *args)
+                outs = d.fn(*args)
+                if not isinstance(outs, (tuple, list)):
+                    outs = (outs,)
+                if len(outs) != len(d.writes):
+                    raise ValueError(
+                        f"kernel {d.name!r} returned {len(outs)} values for "
+                        f"{len(d.writes)} write buffers"
+                    )
+                for w, o in zip(d.writes, outs):
+                    spec = prog.buffers[w].pspec
+                    axes = tuple(a for a in jax.tree.leaves(list(spec)) if a)
+                    mem[w] = _ensure_vma(o.astype(prog.buffers[w].dtype), axes)
+                    if canary_saved:
+                        canary_saved.pop(w, None)  # whole-buffer rewrite
+                if mode == "stream":
+                    tokens[pid] = counters.completion_from(
+                        tokens[pid], *[mem[w] for w in d.writes])
 
-        elif isinstance(d, StartDesc):
-            if tracker is not None:
-                tracker.start(d)
-            batch = batches_by_index[d.batch]
-            use_plan = coalesce and batch.plan is not None
-            # writeValue: bump after all earlier commands of THIS
-            # program's stream.
-            if mode == "stream":
-                deps = [mem[b] for b in pid_bufs[pid]]
-                tokens[pid], _ = counters.tie(tokens[pid], *deps)
-            elif not use_plan:
-                deps = [mem[b] for b in send_bufs_by_batch[d.batch]]
-                tokens[pid], _ = counters.tie(tokens[pid], *deps)
-            # else (dataflow + coalesced): the trigger ties only to the
-            # packed staging buffers, inside _run_coalesced_batch — the
-            # pack already depends on every source slab, so tying the
-            # whole live set would just re-materialize untouched buffers
-            tokens[pid] = counters.bump(tokens[pid])
-            # fire every descriptor in the batch (threshold reached).
-            # Completion is banked per DESTINATION program: a
-            # cross-program channel bumps the receiver's completion
-            # counter, so the receiver's wait gate observes this
-            # sender's completion (trigger stays on the sender's bank).
-            results_by_pid: Dict[int, List[Any]] = {}
-            if use_plan:
-                plan = batch.plan
-                mem, received = _run_coalesced_batch(mem, plan, tokens[pid],
-                                                     mesh_shape,
-                                                     fallbacks=canary_saved)
-                # a fused transfer feeds the completion counter of every
-                # program it carries a final segment for (the deposited
-                # slabs are slices of the payload, so gating on the
-                # payload gates the deposits — and an all-domestic batch
-                # keeps the exact PR-4 graph: one barrier, all payloads)
-                pid_transfers: Dict[int, List[int]] = {}
-                for ci, ch in enumerate(plan.channels):
-                    if not plan.routes[ci]:
-                        continue  # statically dead: deposits zeros only
-                    dpid = pid if ch.dst_pid is None else ch.dst_pid
-                    ti = plan.routes[ci][-1][0]
-                    pid_transfers.setdefault(dpid, []).append(ti)
-                for dpid, tis in pid_transfers.items():
-                    results_by_pid[dpid] = [received[ti]
-                                            for ti in sorted(set(tis))]
-            else:
-                for ch in batch.channels:
-                    mem, r = _run_channel(mem, ch, tokens[pid], mesh_shape,
-                                          fallbacks=canary_saved)
-                    dpid = pid if ch.dst_pid is None else ch.dst_pid
-                    results_by_pid.setdefault(dpid, []).append(r)
-            for coll in batch.colls:
-                mem, r = _run_collective(mem, coll, tokens[pid], prog)
-                if canary_saved:
-                    canary_saved.pop(coll.out, None)  # wholly overwritten
-                results_by_pid.setdefault(pid, []).append(r)
-            for dpid, rs in results_by_pid.items():
-                comp_tokens[dpid] = counters.completion_from(
-                    comp_tokens[dpid], *rs)
+            elif isinstance(d, StartDesc):
+                if tracker is not None:
+                    tracker.start(d)
+                batch = batches_by_index[d.batch]
+                use_plan = coalesce and batch.plan is not None
+                # writeValue: bump after all earlier commands of THIS
+                # program's stream.
+                if mode == "stream":
+                    deps = [mem[b] for b in pid_bufs[pid]]
+                    tokens[pid], _ = counters.tie(tokens[pid], *deps)
+                elif not use_plan:
+                    deps = [mem[b] for b in send_bufs_by_batch[d.batch]]
+                    tokens[pid], _ = counters.tie(tokens[pid], *deps)
+                # else (dataflow + coalesced): the trigger ties only to the
+                # packed staging buffers, inside _run_coalesced_batch — the
+                # pack already depends on every source slab, so tying the
+                # whole live set would just re-materialize untouched buffers
+                tokens[pid] = counters.bump(tokens[pid])
+                # fire every descriptor in the batch (threshold reached).
+                # Completion is banked per DESTINATION program: a
+                # cross-program channel bumps the receiver's completion
+                # counter, so the receiver's wait gate observes this
+                # sender's completion (trigger stays on the sender's bank).
+                results_by_pid: Dict[int, List[Any]] = {}
+                if use_plan:
+                    plan = batch.plan
+                    mem, received = _run_coalesced_batch(mem, plan, tokens[pid],
+                                                         mesh_shape,
+                                                         fallbacks=canary_saved)
+                    # a fused transfer feeds the completion counter of every
+                    # program it carries a final segment for (the deposited
+                    # slabs are slices of the payload, so gating on the
+                    # payload gates the deposits — and an all-domestic batch
+                    # keeps the exact PR-4 graph: one barrier, all payloads)
+                    pid_transfers: Dict[int, List[int]] = {}
+                    for ci, ch in enumerate(plan.channels):
+                        if not plan.routes[ci]:
+                            continue  # statically dead: deposits zeros only
+                        dpid = pid if ch.dst_pid is None else ch.dst_pid
+                        ti = plan.routes[ci][-1][0]
+                        pid_transfers.setdefault(dpid, []).append(ti)
+                    for dpid, tis in pid_transfers.items():
+                        results_by_pid[dpid] = [received[ti]
+                                                for ti in sorted(set(tis))]
+                else:
+                    for ch in batch.channels:
+                        mem, r = _run_channel(mem, ch, tokens[pid], mesh_shape,
+                                              fallbacks=canary_saved)
+                        dpid = pid if ch.dst_pid is None else ch.dst_pid
+                        results_by_pid.setdefault(dpid, []).append(r)
+                for coll in batch.colls:
+                    mem, r = _run_collective(mem, coll, tokens[pid], prog)
+                    if canary_saved:
+                        canary_saved.pop(coll.out, None)  # wholly overwritten
+                    results_by_pid.setdefault(pid, []).append(r)
+                for dpid, rs in results_by_pid.items():
+                    comp_tokens[dpid] = counters.completion_from(
+                        comp_tokens[dpid], *rs)
 
-        elif isinstance(d, WaitDesc):
-            if tracker is not None:
-                tracker.wait(d)
-            # waitValue: gate this program's stream on its completion
-            # counter (another program's descriptors flow right past).
-            if mode == "stream":
-                names = list(pid_bufs[pid])
-                comp_tokens[pid], vals = counters.gate(
-                    comp_tokens[pid], *[mem[n] for n in names])
-                mem.update(zip(names, vals))
-                tokens[pid] = (counters.bump(tokens[pid], 0)
-                               + 0 * comp_tokens[pid])  # stream advances
-            else:
-                names = recv_bufs_by_batch.get(d.batch, [])
-                if names:
+            elif isinstance(d, WaitDesc):
+                if tracker is not None:
+                    tracker.wait(d)
+                # waitValue: gate this program's stream on its completion
+                # counter (another program's descriptors flow right past).
+                if mode == "stream":
+                    names = list(pid_bufs[pid])
                     comp_tokens[pid], vals = counters.gate(
                         comp_tokens[pid], *[mem[n] for n in names])
                     mem.update(zip(names, vals))
-        # Send/Recv/Coll descs themselves are no-ops here: they were
-        # matched into their batch at build time (deferred execution).
+                    tokens[pid] = (counters.bump(tokens[pid], 0)
+                                   + 0 * comp_tokens[pid])  # stream advances
+                else:
+                    names = recv_bufs_by_batch.get(d.batch, [])
+                    if names:
+                        comp_tokens[pid], vals = counters.gate(
+                            comp_tokens[pid], *[mem[n] for n in names])
+                        mem.update(zip(names, vals))
+            # Send/Recv/Coll descs themselves are no-ops here: they were
+            # matched into their batch at build time (deferred execution).
 
     return mem, tokens, comp_tokens
 

@@ -327,8 +327,13 @@ class PersistentEngine(FusedEngine):
             raise ValueError(f"unroll must be >= 1, got {unroll}")
         self.unroll = None if unroll is None else int(unroll)
 
-    # (__call__ inherited: FusedEngine already counts one dispatch per
-    # call — which here covers ALL n_iters iterations.)
+    def __call__(self, mem: Dict[str, jax.Array]):
+        """One dispatch of the whole loop (counted by ``FusedEngine``),
+        in the host span ``st.persistent.dispatch`` with its iteration
+        bound as ``iters``; the span records only while a profiler runs."""
+        with jax.profiler.TraceAnnotation("st.persistent.dispatch",
+                                          iters=self.max_iters):
+            return super().__call__(mem)
 
     # -- lowering -------------------------------------------------------------
 
@@ -424,8 +429,9 @@ def _run_persistent(
             tokens=tokens, comp_tokens=comps, coalesce=coalesce,
             sanitize=sanitize)
         if reduce_fn is not None:  # sees every buffer, slots included
-            val = jnp.asarray(reduce_fn(cur), jnp.float32).reshape(())
-            red = jax.lax.dynamic_update_index_in_dim(red, val, i, axis=0)
+            with jax.named_scope("residual"):
+                val = jnp.asarray(reduce_fn(cur), jnp.float32).reshape(())
+                red = jax.lax.dynamic_update_index_in_dim(red, val, i, axis=0)
         written = {n: cur.pop(n) for n in slots}
         return cur, alt_slots, written, tokens, comps, red
 
@@ -481,8 +487,9 @@ def _run_persistent_while(
             cur, prog=prog, mode=mode, mesh_shape=mesh_shape,
             tokens=tokens, comp_tokens=comps, coalesce=coalesce,
             sanitize=sanitize)
-        val = jnp.asarray(reduce_fn(cur), jnp.float32).reshape(())
-        red = jax.lax.dynamic_update_index_in_dim(red, val, i, axis=0)
+        with jax.named_scope("residual"):
+            val = jnp.asarray(reduce_fn(cur), jnp.float32).reshape(())
+            red = jax.lax.dynamic_update_index_in_dim(red, val, i, axis=0)
         written = {n: cur.pop(n) for n in slots}
         keep_going = jnp.asarray(cond_fn(val), jnp.bool_).reshape(())
         return i + 1, keep_going, cur, alt_slots, written, tokens, comps, red
@@ -562,11 +569,12 @@ def _run_schedule_while(
             act = active[s.name]
             val = None
             if s.name in reduce_fns:
-                val = jnp.asarray(
-                    reduce_fns[s.name](new), jnp.float32).reshape(())
-                rec = jax.lax.dynamic_update_index_in_dim(
-                    reds[s.name], val, i, axis=0)
-                reds[s.name] = jnp.where(act, rec, reds[s.name])
+                with jax.named_scope("residual"):
+                    val = jnp.asarray(
+                        reduce_fns[s.name](new), jnp.float32).reshape(())
+                    rec = jax.lax.dynamic_update_index_in_dim(
+                        reds[s.name], val, i, axis=0)
+                    reds[s.name] = jnp.where(act, rec, reds[s.name])
             done = ndone[s.name] + act.astype(jnp.int32)
             ndone[s.name] = done
             k = jnp.logical_and(act, done < s.n_iters)

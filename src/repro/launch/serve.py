@@ -33,18 +33,6 @@ CLI
   requests arriving as a Poisson process at R req/s (0 = all at t=0),
   decode chunked every C tokens between admission points.
 
-BENCH_serve.json schema (written by ``benchmarks/serve_bench.py``, gated
-by ``benchmarks/run.py serve --check-against BENCH_serve.json``)::
-
-  {
-    "serve/<variant>": {            # host_stepped | resident | continuous
-      "tok_per_s": float,           # tokens emitted / serve wall-clock s
-      "median_ms": float,           # median serve wall-clock over repeats
-      "dispatches": int,            # host dispatches for the request set
-      "p50_ms": float, "p99_ms": float,   # per-request latency percentiles
-    },
-    "_meta": { ... }                # workload stamp: medians only compare
-  }                                 # like-for-like (cf. BENCH_faces.json)
 """
 
 from __future__ import annotations
@@ -68,15 +56,49 @@ from repro.parallel import sharding_ctx
 PAD_TOKEN = -1
 
 
-class _Counted:
-    """Wrap a jitted callable and count host dispatches through it."""
+@dataclasses.dataclass
+class ServeStats:
+    """One :class:`ServeEngine`'s host-side counts since it was built.
 
-    def __init__(self, fn):
-        self._fn = fn
-        self.calls = 0
+    The first four count host dispatches of each jitted program; the
+    rest count what ``serve_continuous``'s rounds did, from the host
+    data each round already holds (its admit mask and the tokens read
+    back), with no device read of their own.
+    """
+    prefill: int = 0
+    decode: int = 0
+    admit_decode: int = 0      # admissions: composed prefill+decode
+    decode_one: int = 0
+    rounds: int = 0            # one dispatch and one host sync each
+    admitted: int = 0          # requests admitted
+    prefill_rows: int = 0      # rows the admission program prefilled
+    decoded: int = 0           # tokens the decode loops emitted
+    steps: int = 0             # decode-loop steps run
+    sync_points: int = 0       # host syncs on a dispatch's results
+
+    @property
+    def dispatches(self) -> int:
+        return self.prefill + self.decode + self.admit_decode + self.decode_one
+
+    def since(self, base: "ServeStats") -> "ServeStats":
+        """The counts made after the snapshot ``base``."""
+        return ServeStats(*(a - b for a, b in zip(
+            dataclasses.astuple(self), dataclasses.astuple(base))))
+
+
+class _Counted:
+    """Wrap a jitted callable; count its host dispatches in ``stats``
+    under its ``name`` (``calls`` reads that count)."""
+
+    def __init__(self, fn, stats: ServeStats, name: str):
+        self._fn, self._stats, self._name = fn, stats, name
+
+    @property
+    def calls(self) -> int:
+        return getattr(self._stats, self._name)
 
     def __call__(self, *args):
-        self.calls += 1
+        setattr(self._stats, self._name, self.calls + 1)
         return self._fn(*args)
 
 
@@ -152,6 +174,11 @@ class ServeEngine:
     All decode-state arguments are donated: the serve chain rotates the
     cache buffers zero-copy across dispatches (the donated inputs are
     deleted — PR-4 slot rotation at the serve layer).
+
+    An admission's prefill lowers in the named scope ``admit`` and every
+    decode loop in ``decode``, the HLO ``op_name`` metadata by which a
+    device trace tells the two apart.  ``stats`` (:class:`ServeStats`)
+    counts the dispatches and the rounds' work.
     """
 
     def __init__(self, cfg: ModelConfig, mesh, *, slots: int,
@@ -165,7 +192,7 @@ class ServeEngine:
         self.prefix_len = self.model._prefix_len()
         self.capacity = self.prefix_len + prompt_len + max_new
         self.chunk = int(chunk) if chunk else max(max_new - 1, 1)
-        self.sync_points = 0
+        self.stats = ServeStats()
 
         pre_shape = ShapeConfig("serve_prefill", prompt_len, slots, "prefill")
         dec_shape = ShapeConfig("serve_decode", self.capacity, slots, "decode")
@@ -188,17 +215,20 @@ class ServeEngine:
                               self.pre.in_shardings[1],
                               self.cache_shardings),
                 out_shardings=(self.pre.out_shardings[0],
-                               self.cache_shardings)))
+                               self.cache_shardings)), self.stats, "prefill")
             donate_state = (1, 2, 3, 4) if donate else ()
             self.decode = _Counted(jax.jit(
-                self._decode_fn, donate_argnums=donate_state))
+                self._decode_fn, donate_argnums=donate_state),
+                self.stats, "decode")
             self.admit_decode = _Counted(jax.jit(
-                self._admit_decode_fn, donate_argnums=donate_state))
+                self._admit_decode_fn, donate_argnums=donate_state),
+                self.stats, "admit_decode")
             # legacy-shaped single-token step for the host-stepped
             # baseline (donates caches, like the old driver)
             self.decode_one = _Counted(jax.jit(
                 self.dec.step_fn, in_shardings=self.dec.in_shardings,
-                out_shardings=self.dec.out_shardings, donate_argnums=(1,)))
+                out_shardings=self.dec.out_shardings, donate_argnums=(1,)),
+                self.stats, "decode_one")
 
     # -- state ----------------------------------------------------------------
 
@@ -212,11 +242,6 @@ class ServeEngine:
         active = jnp.zeros((self.slots,), bool)
         rem = jnp.zeros((self.slots,), jnp.int32)
         return caches, tok, active, rem
-
-    @property
-    def dispatches(self) -> int:
-        return (self.prefill.calls + self.decode.calls
-                + self.admit_decode.calls + self.decode_one.calls)
 
     # -- device-resident decode loop core -------------------------------------
 
@@ -261,9 +286,10 @@ class ServeEngine:
             tok = jnp.where(active, nxt, tok)
             return i + 1, new_caches, tok, active, rem, out, n
 
-        _, caches, tok, active, rem, out, n = jax.lax.while_loop(
-            cond, body,
-            (jnp.zeros((), jnp.int32), caches, tok, active, rem, out0, n0))
+        with jax.named_scope("decode"):
+            _, caches, tok, active, rem, out, n = jax.lax.while_loop(
+                cond, body,
+                (jnp.zeros((), jnp.int32), caches, tok, active, rem, out0, n0))
         return caches, tok, active, rem, out, n
 
     def _decode_fn(self, params, caches, tok, active, rem):
@@ -290,23 +316,24 @@ class ServeEngine:
 
     def _admit_decode_inner(self, params, caches, tok, active, rem,
                             batch_in, admit, new_rem):
-        zero = jax.tree.map(jnp.zeros_like, caches)
-        logits, pre = self.model.prefill(
-            params, batch_in, zero, serve_window=self.serve_window)
-        caches = self.model.select_slots(admit, pre, caches)
-        tok0 = _argmax_tok(logits)
-        first = jnp.where(admit, tok0, PAD_TOKEN)
-        tok = jnp.where(admit, tok0, tok)
-        # the prefill token is emission #1 of the admitted request
-        rem_admitted = new_rem - 1
-        fresh = admit
-        stop = rem_admitted <= 0
-        if self.eos_id >= 0:
-            stop = stop | (tok0 == self.eos_id)
-        stop = stop | (caches["pos"] >= self.capacity)
-        fresh = fresh & ~stop
-        active = jnp.where(admit, fresh, active)
-        rem = jnp.where(admit, rem_admitted, rem)
+        with jax.named_scope("admit"):
+            zero = jax.tree.map(jnp.zeros_like, caches)
+            logits, pre = self.model.prefill(
+                params, batch_in, zero, serve_window=self.serve_window)
+            caches = self.model.select_slots(admit, pre, caches)
+            tok0 = _argmax_tok(logits)
+            first = jnp.where(admit, tok0, PAD_TOKEN)
+            tok = jnp.where(admit, tok0, tok)
+            # the prefill token is emission #1 of the admitted request
+            rem_admitted = new_rem - 1
+            fresh = admit
+            stop = rem_admitted <= 0
+            if self.eos_id >= 0:
+                stop = stop | (tok0 == self.eos_id)
+            stop = stop | (caches["pos"] >= self.capacity)
+            fresh = fresh & ~stop
+            active = jnp.where(admit, fresh, active)
+            rem = jnp.where(admit, rem_admitted, rem)
         caches, tok, active, rem, out, n = self._decode_loop(
             params, caches, tok, active, rem)
         return caches, tok, active, rem, first, out, n
@@ -360,8 +387,7 @@ def serve(cfg: ModelConfig, mesh, *, batch: int, prompt_len: int,
         chunk=gen_len - 1, eos_id=eos_id, serve_window=serve_window)
     assert (eng.slots == batch and eng.chunk == gen_len - 1
             and eng.eos_id == int(eos_id)), "engine/serve shape mismatch"
-    base_disp = eng.dispatches
-    base_dec = eng.decode.calls + eng.decode_one.calls
+    base = dataclasses.replace(eng.stats)
     with mesh:
         if params is None:
             params, _ = eng.model.init(jax.random.PRNGKey(seed))
@@ -388,7 +414,7 @@ def serve(cfg: ModelConfig, mesh, *, batch: int, prompt_len: int,
                 params, caches, tok0, active, rem)
             out = np.asarray(out)
             n_np = np.asarray(n_emit)
-            eng.sync_points += 1
+            eng.stats.sync_points += 1
         else:
             # legacy host-stepped loop (fixed accounting: no per-step
             # host sync — emissions stay on device until the end)
@@ -399,7 +425,7 @@ def serve(cfg: ModelConfig, mesh, *, batch: int, prompt_len: int,
                 cur = _argmax_tok(logits)
                 emitted.append(cur)
             jax.block_until_ready(cur)
-            eng.sync_points += 1
+            eng.stats.sync_points += 1
             out = np.stack([np.asarray(t) for t in emitted], axis=1)
             # host-side EOS truncation (the oracle the resident loop's
             # on-device masking must reproduce exactly)
@@ -415,13 +441,14 @@ def serve(cfg: ModelConfig, mesh, *, batch: int, prompt_len: int,
 
     gen = np.concatenate([tok0_np[:, None], out], axis=1)
     decode_tokens = int(n_np.sum())
+    done = eng.stats.since(base)
     stats = {
         "prefill_s": t_prefill, "decode_s": t_decode,
         "decode_tokens": decode_tokens,
         "tok_per_s": decode_tokens / max(t_decode, 1e-9),
-        "dispatches": eng.dispatches - base_disp,
-        "decode_dispatches": eng.decode.calls + eng.decode_one.calls - base_dec,
-        "sync_points": eng.sync_points,
+        "dispatches": done.dispatches,
+        "decode_dispatches": done.decode + done.decode_one,
+        "sync_points": done.sync_points,
     }
     return gen, stats
 
@@ -433,9 +460,14 @@ def serve(cfg: ModelConfig, mesh, *, batch: int, prompt_len: int,
 
 @dataclasses.dataclass
 class RequestResult:
+    """One served request.  Times are seconds on the serving call's
+    clock: arrival as scheduled, just before the admission dispatch that
+    took it, and the host syncs that returned its first and last token."""
     rid: int
     tokens: np.ndarray        # emitted tokens (prefill token first)
     t_arrive: float
+    t_admit: float
+    t_first: float
     t_done: float
 
     @property
@@ -449,6 +481,23 @@ def poisson_arrivals(n: int, rate: float, rng) -> np.ndarray:
     if rate <= 0:
         return np.zeros(n)
     return np.cumsum(rng.exponential(1.0 / rate, size=n))
+
+
+def _admission_batch(prompts, admit_ids, slots: int, max_new: int):
+    """The admission dispatch's inputs for ``admit_ids`` (slot, request
+    id) pairs: each admitted prompt in its slot's row (zeros elsewhere),
+    the admit mask and the admitted slots' token budgets."""
+    admit_np = np.zeros(slots, bool)
+    new_rem = np.zeros(slots, np.int32)
+    rows = {k: np.asarray(v) for k, v in prompts.items()}
+    batch = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
+             for k, v in rows.items()}
+    for s, rid in admit_ids:
+        admit_np[s] = True
+        new_rem[s] = max_new
+        for k in rows:
+            batch[k][s] = rows[k][rid]
+    return {k: jnp.asarray(v) for k, v in batch.items()}, admit_np, new_rem
 
 
 def serve_continuous(cfg: ModelConfig, mesh, *, slots: int, prompt_len: int,
@@ -470,7 +519,11 @@ def serve_continuous(cfg: ModelConfig, mesh, *, slots: int, prompt_len: int,
     Returns ``(results, stats)`` — per-request
     :class:`RequestResult` (tokens are bit-identical to serving the
     request alone) and aggregate stats (tok/s, p50/p99 latency,
-    dispatch/sync counts).
+    dispatch/sync counts).  The engine's :class:`ServeStats` counts each
+    round's work.  While a profiler runs, each round is the host span
+    ``st.serve.round`` (arguments ``admitted``, ``decoded``, ``steps``)
+    holding ``st.serve.admit_prep`` (the admitted prompts' batch) and
+    ``st.serve.emit`` (reading the results back, retiring requests).
     """
     eng = engine or ServeEngine(
         cfg, mesh, slots=slots, prompt_len=prompt_len, max_new=max_new,
@@ -492,12 +545,12 @@ def serve_continuous(cfg: ModelConfig, mesh, *, slots: int, prompt_len: int,
         slot_req = np.full(slots, -1)          # request id per slot
         emitted: List[List[int]] = [[] for _ in range(n_requests)]
         results: List[Optional[RequestResult]] = [None] * n_requests
+        t_admit = np.full(n_requests, np.nan)
+        t_first = np.full(n_requests, np.nan)
         next_req = 0
         n_done = 0
-        base_prefill = eng.prefill.calls
-        base_admit = eng.admit_decode.calls
-        base_decode = eng.decode.calls
-        base_disp = eng.dispatches
+        st = eng.stats
+        base = dataclasses.replace(st)
         t0 = time.time()
 
         while n_done < n_requests:
@@ -512,63 +565,74 @@ def serve_continuous(cfg: ModelConfig, mesh, *, slots: int, prompt_len: int,
                 time.sleep(min(max(arrivals[next_req] - now, 0.0), 0.01))
                 continue
 
-            if admit_ids:
-                admit_np = np.zeros(slots, bool)
-                new_rem = np.zeros(slots, np.int32)
-                rows = {k: np.asarray(v) for k, v in all_prompts.items()}
-                batch_rows = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-                              for k, v in rows.items()}
-                for s, rid in admit_ids:
-                    admit_np[s] = True
-                    new_rem[s] = max_new
-                    slot_req[s] = rid
-                    for k in rows:
-                        batch_rows[k][s] = rows[k][rid]
-                batch_in = {k: jnp.asarray(v) for k, v in batch_rows.items()}
-                caches, tok, active, rem, first, out, n_emit = eng.admit_decode(
-                    params, caches, tok, active, rem, batch_in,
-                    jnp.asarray(admit_np), jnp.asarray(new_rem))
-            else:
-                caches, tok, active, rem, out, n_emit = eng.decode(
-                    params, caches, tok, active, rem)
-                first = None
+            with jax.profiler.TraceAnnotation("st.serve.round") as span:
+                if admit_ids:
+                    with jax.profiler.TraceAnnotation("st.serve.admit_prep"):
+                        batch_in, admit_np, new_rem = _admission_batch(
+                            all_prompts, admit_ids, slots, max_new)
+                    for s, rid in admit_ids:
+                        slot_req[s] = rid
+                    t_admit[[rid for _, rid in admit_ids]] = time.time() - t0
+                    (caches, tok, active, rem, first, out,
+                     n_emit) = eng.admit_decode(
+                        params, caches, tok, active, rem, batch_in,
+                        jnp.asarray(admit_np), jnp.asarray(new_rem))
+                    st.admitted += len(admit_ids)
+                    st.prefill_rows += slots
+                else:
+                    caches, tok, active, rem, out, n_emit = eng.decode(
+                        params, caches, tok, active, rem)
+                    first = None
 
-            # ONE host sync per round: the admission point
-            out_np = np.asarray(out)
-            act_np = np.asarray(active)
-            first_np = np.asarray(first) if first is not None else None
-            eng.sync_points += 1
-            t_round = time.time() - t0
+                with jax.profiler.TraceAnnotation("st.serve.emit"):
+                    # ONE host sync per round: the admission point
+                    out_np = np.asarray(out)
+                    act_np = np.asarray(active)
+                    first_np = None if first is None else np.asarray(first)
+                    t_round = time.time() - t0
+                    emits = out_np != PAD_TOKEN
+                    decoded, steps = int(emits.sum()), int(emits.any(0).sum())
+                    st.rounds += 1
+                    st.sync_points += 1
+                    st.decoded += decoded
+                    st.steps += steps
 
-            for s in range(slots):
-                rid = slot_req[s]
-                if rid < 0:
-                    continue
-                if first_np is not None and first_np[s] != PAD_TOKEN:
-                    emitted[rid].append(int(first_np[s]))
-                emitted[rid].extend(
-                    int(t) for t in out_np[s] if t != PAD_TOKEN)
-                if not act_np[s]:
-                    results[rid] = RequestResult(
-                        rid=rid, tokens=np.asarray(emitted[rid], np.int32),
-                        t_arrive=float(arrivals[rid]), t_done=t_round)
-                    slot_req[s] = -1
-                    n_done += 1
+                    for s in range(slots):
+                        rid = slot_req[s]
+                        if rid < 0:
+                            continue
+                        if first_np is not None and first_np[s] != PAD_TOKEN:
+                            emitted[rid].append(int(first_np[s]))
+                        emitted[rid].extend(out_np[s, emits[s]].tolist())
+                        if emitted[rid] and np.isnan(t_first[rid]):
+                            t_first[rid] = t_round
+                        if not act_np[s]:
+                            results[rid] = RequestResult(
+                                rid=rid,
+                                tokens=np.asarray(emitted[rid], np.int32),
+                                t_arrive=float(arrivals[rid]),
+                                t_admit=float(t_admit[rid]),
+                                t_first=float(t_first[rid]), t_done=t_round)
+                            slot_req[s] = -1
+                            n_done += 1
+                span.set_metadata(admitted=len(admit_ids), decoded=decoded,
+                                  steps=steps)
 
         t_total = time.time() - t0
     lat = np.asarray([r.latency_s for r in results])
     total_tokens = int(sum(len(e) for e in emitted))
+    done = st.since(base)
     stats = {
         "total_s": t_total,
         "total_tokens": total_tokens,
         "tok_per_s": total_tokens / max(t_total, 1e-9),
         "p50_ms": float(np.percentile(lat, 50) * 1e3),
         "p99_ms": float(np.percentile(lat, 99) * 1e3),
-        "dispatches": eng.dispatches - base_disp,
-        "admit_dispatches": eng.admit_decode.calls - base_admit,
-        "decode_dispatches": eng.decode.calls - base_decode,
-        "prefill_dispatches": eng.prefill.calls - base_prefill,
-        "sync_points": eng.sync_points,
+        "dispatches": done.dispatches,
+        "admit_dispatches": done.admit_decode,
+        "decode_dispatches": done.decode,
+        "prefill_dispatches": done.prefill,
+        "sync_points": done.sync_points,
     }
     return results, stats
 

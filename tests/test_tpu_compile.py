@@ -107,16 +107,19 @@ def test_unpack_segments_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def _faces_compiled(devices, grid, pack):
+def _faces_compiled(devices, grid, pack, residual=False):
     from repro.core.engine_persistent import PersistentEngine
-    from repro.core.halo import AXES3, FacesConfig, build_faces_program
+    from repro.core.halo import (AXES3, FacesConfig, build_faces_program,
+                                 global_residual_fn)
     from repro.parallel import make_mesh
 
     mesh = make_mesh(grid, AXES3, devices=devices)
     cfg = FacesConfig(grid=grid, points=(N, N, N), dtype="float32",
                       periodic=True, damping=0.03, pack=pack)
     prog = build_faces_program(cfg, mesh).persistent(10)
-    eng = PersistentEngine(prog, mode="dataflow", donate=True)
+    eng = PersistentEngine(prog, mode="dataflow", donate=True,
+                           reduce_fn=global_residual_fn(cfg) if residual
+                           else None)
     return eng.lower().compile()
 
 
@@ -134,3 +137,16 @@ def test_faces_2x2x1_compiles_on_2x2(topo, pack):
     # the halo crosses chips: the exchange is real collective traffic
     assert "collective-permute" in text
     assert ("tpu_custom_call" in text) == (pack == "pallas")
+
+
+def test_faces_scopes_survive_the_tpu_compiler(topo):
+    """The benchmark's Faces program, compiled for one chip: its fusions
+    keep the queue ops' scopes in their ``op_name``, by which a trace's
+    device time is named per stage."""
+    import re
+
+    text = _faces_compiled(topo.devices[:1], (1, 1, 1), "jnp",
+                           residual=True).as_text()
+    fusions = re.findall(r' fusion\(.*op_name="([^"]*)"', text)
+    scopes = {p for name in fusions for p in name.split("/")[:-1]}
+    assert {"interior", "exchange", "residual", "unpack0"} <= scopes

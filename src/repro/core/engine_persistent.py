@@ -330,9 +330,11 @@ class PersistentEngine(FusedEngine):
     def __call__(self, mem: Dict[str, jax.Array]):
         """One dispatch of the whole loop (counted by ``FusedEngine``),
         in the host span ``st.persistent.dispatch`` with its iteration
-        bound as ``iters``; the span records only while a profiler runs."""
+        bound as ``iters`` and the program's ``span_args``; the span
+        records only while a profiler runs."""
         with jax.profiler.TraceAnnotation("st.persistent.dispatch",
-                                          iters=self.max_iters):
+                                          iters=self.max_iters,
+                                          **dict(self.program.span_args)):
             return super().__call__(mem)
 
     # -- lowering -------------------------------------------------------------

@@ -147,16 +147,49 @@ def _make_unpack_fn(region, pack_mode: str):
     return unpack
 
 
-def _interior_fn(u):
-    """Step-4 overlap kernel: a cheap local stencil on the interior."""
-    core = u[0, 0, 0]
-    smoothed = core + 0.125 * (
-        jnp.roll(core, 1, 0) + jnp.roll(core, -1, 0)
+def stencil_lowering(points, dtype) -> str:
+    """Which lowering the interior stencil of a ``points`` block takes:
+    ``"pallas"`` where the block tiles the one-pass kernel
+    (:func:`repro.kernels.faces_stencil.stencil7_tiles`), else
+    ``"jnp"``.  The shape alone decides; both compute one algorithm."""
+    from repro.kernels.faces_stencil import stencil7_tiles
+
+    return "pallas" if stencil7_tiles(tuple(points), dtype) else "jnp"
+
+
+def _stencil7(core, lo, hi):
+    """7-point periodic smoothing of ``core`` with x-ghost planes
+    ``lo``/``hi``: the one-pass Pallas kernel where the block tiles it,
+    else the jnp expression.  The x-neighbours are concat-with-ghost
+    shifts (pure copies), and both lowerings add in the same order."""
+    if stencil_lowering(core.shape, core.dtype) == "pallas":
+        from repro.kernels import ops as kops
+
+        return kops.faces_stencil7(core, lo, hi)
+    xm = jnp.concatenate([lo, core[:-1]], axis=0)
+    xp = jnp.concatenate([core[1:], hi], axis=0)
+    return core + 0.125 * (
+        xm + xp
         + jnp.roll(core, 1, 1) + jnp.roll(core, -1, 1)
         + jnp.roll(core, 1, 2) + jnp.roll(core, -1, 2)
         - 6.0 * core
     )
-    return smoothed[None, None, None]
+
+
+def _interior_fn(u):
+    """Step-4 overlap kernel: a cheap local stencil on the interior,
+    periodic in x through the block's own wrap planes."""
+    core = u[0, 0, 0]
+    return _stencil7(core, core[-1:], core[:1])[None, None, None]
+
+
+def _with_stencil_arg(prog: STProgram, cfg: FacesConfig, points) -> STProgram:
+    """``prog`` with the stencil's lowering as an argument of the
+    engines' dispatch span (where the program has the stencil)."""
+    if not cfg.interior_compute:
+        return prog
+    return dataclasses.replace(prog, span_args=(
+        ("stencil", stencil_lowering(points, np.dtype(cfg.dtype))),))
 
 
 def build_faces_program(cfg: FacesConfig, mesh,
@@ -195,7 +228,8 @@ def build_faces_program(cfg: FacesConfig, mesh,
     else:
         raise ValueError(cfg.granularity)
 
-    return q.build(name=name or f"faces_{cfg.granularity}", coalesce=coalesce)
+    prog = q.build(name=name or f"faces_{cfg.granularity}", coalesce=coalesce)
+    return _with_stencil_arg(prog, cfg, cfg.points)
 
 
 def _emit_direct26(q: STQueue, cfg: FacesConfig, msg_in, msg_out):
@@ -431,24 +465,14 @@ _GHOST_TAG_LO, _GHOST_TAG_HI = 0, 1
 def _part_interior_fn(u, glo, ghi):
     """Step-4 overlap stencil of one x-part, ghost planes substituted.
 
-    Bit-identical to :func:`_interior_fn` on the unsplit block: the
-    x-rolls become concat-with-ghost shifts (pure copies — the
-    neighbor-part planes exchanged this iteration), and the elementwise
-    addition order is kept exactly, so every float op matches the
-    full-domain kernel's.
+    Bit-identical to :func:`_interior_fn` on the unsplit block: both
+    call :func:`_stencil7`, here with the neighbour parts' planes
+    exchanged this iteration as the ghosts (``glo`` the last plane of
+    the part below, ``ghi`` the first plane of the part above, in a
+    ring), there with the block's own wrap planes, so every float op
+    matches the full-domain kernel's.
     """
-    core = u[0, 0, 0]
-    lo = glo[0, 0, 0]   # last plane of the part below (ring)
-    hi = ghi[0, 0, 0]   # first plane of the part above (ring)
-    xm = jnp.concatenate([lo, core[:-1]], axis=0)   # == roll(full, +1, 0)
-    xp = jnp.concatenate([core[1:], hi], axis=0)    # == roll(full, -1, 0)
-    smoothed = core + 0.125 * (
-        xm + xp
-        + jnp.roll(core, 1, 1) + jnp.roll(core, -1, 1)
-        + jnp.roll(core, 1, 2) + jnp.roll(core, -1, 2)
-        - 6.0 * core
-    )
-    return smoothed[None, None, None]
+    return _stencil7(u[0, 0, 0], glo[0, 0, 0], ghi[0, 0, 0])[None, None, None]
 
 
 def build_faces_part_program(cfg: FacesConfig, mesh, part: int, n_parts: int,
@@ -579,7 +603,8 @@ def build_faces_part_program(cfg: FacesConfig, mesh, part: int, n_parts: int,
         q.enqueue_kernel(_make_unpack_fn(region, cfg.pack),
                          ["u", msg_in[d]], ["u"], name=f"unpack{i}")
     _emit_damping(q, cfg)
-    return q.build(name=names[part], coalesce=coalesce)
+    prog = q.build(name=names[part], coalesce=coalesce)
+    return _with_stencil_arg(prog, cfg, cfgp.points)
 
 
 def run_faces_pipelined(cfg: FacesConfig, mesh, u0, *,

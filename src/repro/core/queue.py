@@ -216,6 +216,10 @@ class STProgram:
     # ``n_iters``, which becomes the max_iters safety bound).  Set via
     # ``persistent(n_iters, until=...)``.
     until: Optional[Callable[[Any], Any]] = None
+    # Build-time choices recorded as (name, value) pairs, which engines
+    # report as arguments of their host dispatch span (Faces records
+    # which lowering its interior stencil took).
+    span_args: Tuple[Tuple[str, Any], ...] = ()
 
     @property
     def n_batches(self) -> int:

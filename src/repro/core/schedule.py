@@ -539,6 +539,8 @@ def compose(*programs: STProgram, name: Optional[str] = None,
         until=None,
         subs=tuple(subs),
         links=tuple(links_meta),
+        span_args=tuple(dict(a for p in programs
+                             for a in p.span_args).items()),
     )
     from .verify import run_verify  # local import: verify imports queue
     run_verify(sched, verify)

@@ -7,6 +7,7 @@ program is lowered for the CPU.
 """
 from . import ops, ref
 from .ops import (
+    faces_stencil7,
     flash_attention,
     halo_pack,
     halo_unpack_add,
@@ -15,6 +16,6 @@ from .ops import (
 )
 
 __all__ = [
-    "ops", "ref", "flash_attention", "halo_pack", "halo_unpack_add",
-    "rmsnorm", "ssd_scan",
+    "ops", "ref", "faces_stencil7", "flash_attention", "halo_pack",
+    "halo_unpack_add", "rmsnorm", "ssd_scan",
 ]

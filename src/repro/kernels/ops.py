@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from .faces_stencil import faces_stencil7_call
 from .flash_attention import flash_attention_call
 from .halo_pack import halo_pack_call, halo_unpack_add_call
 from .rmsnorm import rmsnorm_call
@@ -62,6 +63,22 @@ def _halo_unpack_add(u, msg, hregion):
 def halo_unpack_add(u: jax.Array, msg: jax.Array,
                     region: Tuple[slice, ...]) -> jax.Array:
     return _halo_unpack_add(u, msg, _hashable_region(region))
+
+
+# -- Faces interior stencil ----------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _faces_stencil7(core, lo, hi, block_planes):
+    return _on_target(faces_stencil7_call, core, lo, hi,
+                      block_planes=block_planes)
+
+
+def faces_stencil7(core: jax.Array, lo: jax.Array, hi: jax.Array, *,
+                   block_planes: Optional[int] = None) -> jax.Array:
+    """7-point periodic smoothing of a (px, py, pz) block with x-ghost
+    planes ``lo``/``hi`` (see :mod:`.faces_stencil`)."""
+    return _faces_stencil7(core, lo, hi, block_planes)
 
 
 # -- rmsnorm -------------------------------------------------------------------
@@ -126,6 +143,6 @@ def ssd_scan(x, dt, A, Bm, C, *, init_state=None, chunk: int = 128,
 
 
 __all__ = [
-    "halo_pack", "halo_unpack_add", "rmsnorm", "flash_attention", "ssd_scan",
-    "ref",
+    "halo_pack", "halo_unpack_add", "faces_stencil7", "rmsnorm",
+    "flash_attention", "ssd_scan", "ref",
 ]

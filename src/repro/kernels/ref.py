@@ -28,6 +28,19 @@ def halo_unpack_add(u: jax.Array, msg: jax.Array, region: Tuple[slice, ...]) -> 
     return u.at[region].add(msg.astype(u.dtype))
 
 
+def faces_stencil7(core: jax.Array, lo: jax.Array, hi: jax.Array) -> jax.Array:
+    """7-point periodic smoothing of a (px, py, pz) block: ``lo``/``hi``
+    (1, py, pz) are the x-neighbours of the first and last plane, y and
+    z wrap inside the block."""
+    ext = jnp.concatenate([lo, core, hi], axis=0)
+    return core + 0.125 * (
+        ext[:-2] + ext[2:]
+        + jnp.roll(core, 1, 1) + jnp.roll(core, -1, 1)
+        + jnp.roll(core, 1, 2) + jnp.roll(core, -1, 2)
+        - 6.0 * core
+    )
+
+
 # --------------------------------------------------------------------------
 # RMSNorm
 # --------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret) vs pure-jnp oracle."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -50,6 +51,58 @@ def test_halo_unpack_add_matches_ref(shape, region):
     np.testing.assert_allclose(
         np.asarray(ops.halo_unpack_add(u, msg, region)),
         np.asarray(ref.halo_unpack_add(u, msg, region)), rtol=1e-6)
+
+
+# --------------------------------------------------------------------------
+# Faces interior stencil
+# --------------------------------------------------------------------------
+
+
+def _rolled_stencil(core):
+    """The Faces interior stencil as ``jnp.roll`` expresses it."""
+    return core + 0.125 * (
+        jnp.roll(core, 1, 0) + jnp.roll(core, -1, 0)
+        + jnp.roll(core, 1, 1) + jnp.roll(core, -1, 1)
+        + jnp.roll(core, 1, 2) + jnp.roll(core, -1, 2)
+        - 6.0 * core
+    )
+
+
+def _assert_ulps(got, want, ulps=2):
+    """Equal to ``ulps`` float32 units in the last place of the largest
+    value (contraction into fused multiply-adds may round otherwise)."""
+    want = np.asarray(want)
+    tol = ulps * np.spacing(np.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=tol)
+
+
+@pytest.mark.parametrize("shape,block_planes", [
+    ((8, 8, 128), None), ((8, 8, 128), 2), ((8, 8, 128), 1),
+    ((16, 16, 256), None), ((16, 16, 256), 4),
+    ((2, 8, 128), None), ((2, 8, 128), 1),
+])
+def test_faces_stencil7_matches_ref(shape, block_planes):
+    rng = np.random.RandomState(3)
+    core = rand(rng, shape, "float32")
+    # ghost planes that are not the block's own wrap planes
+    lo, hi = (rand(rng, (1,) + shape[1:], "float32") for _ in range(2))
+    _assert_ulps(ops.faces_stencil7(core, lo, hi, block_planes=block_planes),
+                 ref.faces_stencil7(core, lo, hi))
+    # with its own wrap planes as ghosts it is the rolled expression
+    _assert_ulps(ops.faces_stencil7(core, core[-1:], core[:1],
+                                    block_planes=block_planes),
+                 jax.jit(_rolled_stencil)(core))
+
+
+@pytest.mark.parametrize("shape", [(4, 3, 5), (4, 4, 4), (1, 8, 128)])
+def test_faces_untiled_stencil_is_the_rolled_expression(shape):
+    from repro.core.halo import _interior_fn, stencil_lowering
+
+    assert stencil_lowering(shape, np.float32) == "jnp"
+    u = rand(np.random.RandomState(4), (1, 1, 1) + shape, "float32")
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(_interior_fn)(u))[0, 0, 0],
+        np.asarray(jax.jit(_rolled_stencil)(u[0, 0, 0])))
 
 
 # --------------------------------------------------------------------------

@@ -424,6 +424,28 @@ def test_linked_no_interior_compute():
     np.testing.assert_array_equal(got, np.asarray(full["u"]))
 
 
+def test_part_stencils_bitmatch_the_whole_block_on_the_kernel():
+    """The linked split's stencil where the block tiles the Pallas
+    kernel (interpreted here): two x-halves, each given the other's
+    planes as ghosts, are bit for bit the whole block's stencil."""
+    import jax.numpy as jnp
+
+    from repro.core.halo import (_interior_fn, _part_interior_fn,
+                                 stencil_lowering)
+
+    shape, half = (16, 8, 128), 8
+    assert stencil_lowering(shape, np.float32) == "pallas"
+    assert stencil_lowering((half,) + shape[1:], np.float32) == "pallas"
+    u = jnp.asarray(np.random.RandomState(5).randn(1, 1, 1, *shape),
+                    jnp.float32)
+    a, b = u[:, :, :, :half], u[:, :, :, half:]
+    # a ring of two: each half's ghosts are the other's end planes
+    pa = _part_interior_fn(a, b[:, :, :, -1:], b[:, :, :, :1])
+    pb = _part_interior_fn(b, a[:, :, :, -1:], a[:, :, :, :1])
+    np.testing.assert_array_equal(np.concatenate([pa, pb], axis=3),
+                                  np.asarray(_interior_fn(u)))
+
+
 # -- multi-device matrix (subprocess, slow lane) ------------------------------
 
 

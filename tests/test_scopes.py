@@ -8,6 +8,7 @@ and the served tokens are the same as with every scope taken out.
 """
 
 import contextlib
+import dataclasses
 import glob
 import os
 import re
@@ -235,6 +236,23 @@ def _program_spans(log_dir):
     return out
 
 
+@pytest.mark.parametrize("points,stencil", [((4, 3, 5), "jnp"),
+                                            ((8, 8, 128), "pallas")])
+def test_dispatch_span_names_the_stencil_lowering(tmp_path, points, stencil):
+    cfg = dataclasses.replace(FACES, points=points)
+    faces = PersistentEngine(
+        build_faces_program(cfg, make_mesh((1, 1, 1), AXES3)).persistent(2))
+    mem = faces.init_buffers()
+    faces(mem)          # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(faces(mem))
+    finally:
+        jax.profiler.stop_trace()
+    assert [a["stencil"] for n, a in _program_spans(str(tmp_path))
+            if n == "st.persistent.dispatch"] == [stencil]
+
+
 def test_spans_record_while_a_profiler_runs(tmp_path, cfg, mesh, params,
                                             prompts):
     mesh3 = make_mesh((1, 1, 1), AXES3)
@@ -251,7 +269,7 @@ def test_spans_record_while_a_profiler_runs(tmp_path, cfg, mesh, params,
     spans = _program_spans(str(tmp_path))
     names = [n for n, _ in spans]
     assert [a for n, a in spans if n == "st.persistent.dispatch"] == [
-        {"iters": 3}]
+        {"iters": 3, "stencil": "jnp"}]
     rounds = [a for n, a in spans if n == "st.serve.round"]
     st = eng.stats
     assert len(rounds) == st.rounds == names.count("st.serve.emit")

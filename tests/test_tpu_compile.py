@@ -130,13 +130,47 @@ def test_faces_pallas_persistent_compiles_at_256_on_one_chip(topo):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
+def _kernel_calls(text):
+    """The Mosaic kernels of a compiled program: (stencil, other) lines."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    stencil = [line for line in calls if "faces_stencil7" in line]
+    return stencil, [line for line in calls if line not in stencil]
+
+
 @pytest.mark.parametrize("pack", ["jnp", "pallas"])
 def test_faces_2x2x1_compiles_on_2x2(topo, pack):
     compiled = _faces_compiled(topo.devices, (2, 2, 1), pack)
     text = compiled.as_text()
     # the halo crosses chips: the exchange is real collective traffic
     assert "collective-permute" in text
-    assert ("tpu_custom_call" in text) == (pack == "pallas")
+    stencil, halo = _kernel_calls(text)
+    # the 256³ block tiles the stencil kernel whatever the packing
+    assert stencil
+    assert bool(halo) == (pack == "pallas")
+
+
+def test_faces_stencil7_compiles_at_256(one_chip):
+    from repro.kernels import ops
+    core = jax.ShapeDtypeStruct((N, N, N), jnp.float32, sharding=one_chip)
+    plane = jax.ShapeDtypeStruct((1, N, N), jnp.float32, sharding=one_chip)
+    text = _compiled_text(ops.faces_stencil7, core, plane, plane)
+    assert "tpu_custom_call" in text
+
+
+def test_faces_persistent_stencil_is_one_kernel_at_256(topo):
+    """The benchmark's Faces program at 256³ runs its interior stencil
+    as the one-pass kernel: no shifted copy of the field (one plane
+    short along an axis, as a roll's slices are) is left in the
+    program."""
+    import re
+
+    text = _faces_compiled(topo.devices[:1], (1, 1, 1), "jnp",
+                           residual=True).as_text()
+    stencil, halo = _kernel_calls(text)
+    assert stencil and not halo
+    rolled = re.compile(rf"f32\[({N},{N},{N - 1}|{N},{N - 1},{N}|{N - 1},{N},{N})\]")
+    assert not rolled.search(text)
 
 
 def test_faces_scopes_survive_the_tpu_compiler(topo):
